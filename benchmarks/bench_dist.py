@@ -231,12 +231,13 @@ def _sharded_prefill_result(csv: List[str], smoke: bool) -> Dict:
         return _bench_sharded_prefill(csv, smoke)
     # single-device platform: jax already initialized, so the forced host
     # device count has to come from a subprocess (the launch/dryrun.py
-    # trick) — the sweep still runs instead of silently skipping
+    # trick) — the sweep still runs instead of silently skipping.  The
+    # child is pinned to the CPU: this parent may hold the accelerator.
     import os
     import subprocess
     import sys
 
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_dist", "--prefill-only"]

@@ -15,6 +15,8 @@ import sys
 import time
 from typing import List
 
+from repro.api.persistent_cache import enable_persistent_cache
+
 from . import (bench_buffers, bench_compile_overhead, bench_control_flow,
                bench_dist, bench_fig3_frameworks, bench_fig4_static_gap,
                bench_obs, bench_roofline, bench_serve, bench_table2_nimble,
@@ -43,6 +45,7 @@ def main() -> None:
                     help="tiny shapes, 1-2 iters, no GPU assumptions (CI)")
     args = ap.parse_args()
     names = args.only.split(",") if args.only else list(SUITES)
+    enable_persistent_cache()
 
     print("name,us_per_call,derived")
     csv: List[str] = []

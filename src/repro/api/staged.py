@@ -426,6 +426,14 @@ class Compiled:
         launch), and the host-dispatch vs entry-call wall split."""
         return self._mstats.cost_dict()
 
+    def bucket_programs(self) -> Dict[Tuple[int, ...], Any]:
+        """This artifact's cached bucket entries, keyed by bucket
+        signature — under the AOT backends each is a ``jax.stages.Compiled``
+        whose ``as_text()`` is the program the device runs."""
+        return {k[2]: entry for k, entry in list(self.cache._entries.items())
+                if len(k) == 3 and k[0] == "bucket"
+                and k[1] == self._fingerprint}
+
     def compile_counts(self) -> Dict[str, int]:
         """Per-artifact compile counts (meaningful under shared caches)."""
         return {"bucket": self._bucket_compiles,
@@ -487,14 +495,12 @@ class Compiled:
             "symbolic_peak_no_reuse": plan.symbolic_peak_no_reuse(),
         })
         per_bucket: Dict[str, Any] = {}
-        for k in list(self.cache._entries):
-            if len(k) != 3 or k[0] != "bucket" or k[1] != self._fingerprint:
-                continue
-            bindings = {s.uid: int(v) for s, v in zip(low.syms, k[2])}
+        for key in self.bucket_programs():
+            bindings = {s.uid: int(v) for s, v in zip(low.syms, key)}
             peaks = plan.concrete_peaks(low.graph, bindings)
             reduction = (peaks["no_reuse_bytes"] / peaks["arena_bytes"]
                          if peaks["arena_bytes"] else 1.0)
-            per_bucket[str(tuple(k[2]))] = {
+            per_bucket[str(tuple(key))] = {
                 **peaks, "reduction": round(reduction, 3)}
         mem["per_bucket"] = per_bucket
         return mem

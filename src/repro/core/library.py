@@ -32,7 +32,7 @@ class LibraryChoice:
         return f"<LibraryChoice {self.name}>"
 
 
-def pick(m: int, k: int, n: int, *, interpret: bool = True) -> LibraryChoice:
+def pick(m: int, k: int, n: int) -> LibraryChoice:
     """Choose the GEMM implementation for a runtime (m, k, n)."""
     from ..kernels.matmul.ops import matmul, select_gemm_version
 
@@ -42,4 +42,4 @@ def pick(m: int, k: int, n: int, *, interpret: bool = True) -> LibraryChoice:
         return LibraryChoice("vendor:xla_dot", jnp.dot)
     return LibraryChoice(
         f"library:{version}",
-        lambda a, b: matmul(a, b, version=version, interpret=interpret))
+        lambda a, b: matmul(a, b, version=version))

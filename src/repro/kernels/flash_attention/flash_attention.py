@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call
+
 __all__ = ["flash_attention_kernel"]
 
 _NEG_INF = -1e30
@@ -101,7 +103,6 @@ def flash_attention_kernel(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     b, h, sq, d = q.shape
     _, hkv, sk, _ = k.shape
@@ -114,7 +115,7 @@ def flash_attention_kernel(
 
     body = functools.partial(_fa_body, scale=scale, causal=causal,
                              block_q=block_q, block_k=block_k)
-    return pl.pallas_call(
+    return pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -136,5 +137,4 @@ def flash_attention_kernel(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
     )(jnp.asarray(lens, jnp.int32), q, k, v)

@@ -26,8 +26,7 @@ def _pick_blocks(sq: int, sk: int):
 
 def flash_attention(q, k, v, lens=None, *, causal=True, scale=None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None,
-                    interpret: bool = True) -> jax.Array:
+                    block_k: Optional[int] = None) -> jax.Array:
     """q (B,H,Sq,D) × kv (B,Hkv,Sk,D), per-batch valid kv lens."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -43,17 +42,14 @@ def flash_attention(q, k, v, lens=None, *, causal=True, scale=None,
             kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
             vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
             out = flash_attention_kernel(qp, kp, vp, lens, causal=causal,
-                                         scale=scale, block_q=bq, block_k=bk,
-                                         interpret=interpret)
+                                         scale=scale, block_q=bq, block_k=bk)
             return out[:, :, :sq]
         block_q, block_k = bq, bk
     return flash_attention_kernel(q, k, v, lens, causal=causal, scale=scale,
-                                  block_q=block_q, block_k=block_k,
-                                  interpret=interpret)
+                                  block_q=block_q, block_k=block_k)
 
 
-def flash_decode(q, k_cache, v_cache, lens, *, scale=None,
-                 interpret: bool = True) -> jax.Array:
+def flash_decode(q, k_cache, v_cache, lens, *, scale=None) -> jax.Array:
     """Single-token decode: q (B,H,1,D) against cache (B,Hkv,Smax,D).
 
     Reuses the prefill kernel at block_q=8 (first row valid) — correct for
@@ -65,5 +61,5 @@ def flash_decode(q, k_cache, v_cache, lens, *, scale=None,
     assert sq == 1
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, 7), (0, 0)))
     out = flash_attention(qp, k_cache, v_cache, lens, causal=False,
-                          scale=scale, interpret=interpret)
+                          scale=scale)
     return out[:, :, :1]

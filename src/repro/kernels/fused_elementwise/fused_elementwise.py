@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call, widen
+
 __all__ = ["fused_elementwise_kernel"]
 
 
@@ -35,7 +37,7 @@ def _kernel_body(expr: Callable, n_in: int, n_out: int):
         out_refs = refs[n_in:n_in + n_out]
         i = pl.program_id(0)
         block = out_refs[0].shape[0]
-        xs = [r[...] for r in in_refs]
+        xs = [widen(r[...]) for r in in_refs]
         ys = expr(*xs)
         if not isinstance(ys, (tuple, list)):
             ys = (ys,)
@@ -43,7 +45,7 @@ def _kernel_body(expr: Callable, n_in: int, n_out: int):
         idx = jax.lax.broadcasted_iota(jnp.int32, (block,), 0) + i * block
         mask = idx < n_valid
         for r, y in zip(out_refs, ys):
-            r[...] = jnp.where(mask, y, jnp.zeros_like(y))
+            r[...] = jnp.where(mask, y, jnp.zeros_like(y)).astype(r.dtype)
 
     return body
 
@@ -55,7 +57,6 @@ def fused_elementwise_kernel(
     out_dtypes: Sequence,
     *,
     block: int = 1024,
-    interpret: bool = True,
 ) -> List[jax.Array]:
     """Run ``expr`` (an unrolled fusion cluster) over flattened inputs.
 
@@ -68,7 +69,7 @@ def fused_elementwise_kernel(
     n_in, n_out = len(inputs), len(out_dtypes)
     grid = (total // block,)
     spec = pl.BlockSpec((block,), lambda i, s: (i,))
-    return pl.pallas_call(
+    return pallas_call(
         _kernel_body(expr, n_in, n_out),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -77,5 +78,4 @@ def fused_elementwise_kernel(
             out_specs=[spec] * n_out,
         ),
         out_shape=[jax.ShapeDtypeStruct((total,), dt) for dt in out_dtypes],
-        interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1), *inputs)

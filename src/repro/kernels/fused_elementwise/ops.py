@@ -35,8 +35,7 @@ def select_version(total_padded: int) -> int:
 
 
 def fused_elementwise(expr: Callable, inputs: Sequence[jax.Array], n_valid,
-                      out_dtypes: Sequence = None, *,
-                      interpret: bool = True) -> List[jax.Array]:
+                      out_dtypes: Sequence = None) -> List[jax.Array]:
     """Flatten inputs, pick a kernel version, run the fused cluster."""
     shape = inputs[0].shape
     flat = [jnp.ravel(x) for x in inputs]
@@ -51,8 +50,8 @@ def fused_elementwise(expr: Callable, inputs: Sequence[jax.Array], n_valid,
         flat = [jnp.pad(x, (0, pad)) for x in flat]
         block = select_version(total + pad)
         outs = fused_elementwise_kernel(expr, flat, n_valid, out_dtypes,
-                                        block=block, interpret=interpret)
+                                        block=block)
         return [o[:total].reshape(shape) for o in outs]
     outs = fused_elementwise_kernel(expr, flat, n_valid, out_dtypes,
-                                    block=block, interpret=interpret)
+                                    block=block)
     return [o.reshape(shape) for o in outs]

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call, widen
+
 __all__ = ["fused_reduce_kernel", "REDUCE_IDENTITY"]
 
 REDUCE_IDENTITY = {"sum": 0.0, "max": -jnp.inf, "min": jnp.inf, "prod": 1.0}
@@ -34,19 +36,20 @@ def _kernel_body(expr: Callable, kind: str, n_in: int):
     def body(len_ref, *refs):
         in_refs = refs[:n_in]
         out_ref = refs[n_in]
-        xs = [r[...] for r in in_refs]  # (block_r, C)
+        xs = [widen(r[...]) for r in in_refs]  # (block_r, C)
         y = expr(*xs)
         c = y.shape[1]
         n_valid = len_ref[0]
         col = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
         y = jnp.where(col < n_valid, y, jnp.asarray(identity, y.dtype))
-        out_ref[...] = reducer(y, axis=1, keepdims=True)
+        out_ref[...] = reducer(y, axis=1, keepdims=True).astype(
+            out_ref.dtype)
 
     return body
 
 
 def fused_reduce_kernel(expr: Callable, inputs, n_valid_cols, kind: str,
-                        *, block_r: int = 8, interpret: bool = True):
+                        *, block_r: int = 8):
     """Reduce ``expr(*inputs)`` over axis 1 with masked dynamic length.
 
     inputs: (R, C) arrays, R % block_r == 0.  Returns (R,).
@@ -54,7 +57,7 @@ def fused_reduce_kernel(expr: Callable, inputs, n_valid_cols, kind: str,
     r, c = inputs[0].shape
     assert r % block_r == 0, (r, block_r)
     spec = pl.BlockSpec((block_r, c), lambda i, s: (i, 0))
-    out = pl.pallas_call(
+    out = pallas_call(
         _kernel_body(expr, kind, len(inputs)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -63,6 +66,5 @@ def fused_reduce_kernel(expr: Callable, inputs, n_valid_cols, kind: str,
             out_specs=pl.BlockSpec((block_r, 1), lambda i, s: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((r, 1), inputs[0].dtype),
-        interpret=interpret,
     )(jnp.asarray(n_valid_cols, jnp.int32).reshape(1), *inputs)
     return out[:, 0]

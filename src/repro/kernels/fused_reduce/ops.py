@@ -30,8 +30,7 @@ def select_row_block(r: int, c: int, itemsize: int = 4) -> int:
 
 
 def fused_reduce(expr: Callable, inputs: Sequence[jax.Array], n_valid_cols,
-                 kind: str = "sum", *, axis: int = -1,
-                 interpret: bool = True) -> jax.Array:
+                 kind: str = "sum", *, axis: int = -1) -> jax.Array:
     """Reduce ``expr(*inputs)`` over ``axis`` with dynamic valid length.
 
     ``axis`` may be any single dimension; non-last axes are moved last by
@@ -53,8 +52,8 @@ def fused_reduce(expr: Callable, inputs: Sequence[jax.Array], n_valid_cols,
         pad = (-r) % b
         flat = [jnp.pad(x, ((0, pad), (0, 0))) for x in flat]
         out = fused_reduce_kernel(expr, flat, n_valid_cols, kind,
-                                  block_r=b, interpret=interpret)
+                                  block_r=b)
         return out[:r].reshape(lead)
     out = fused_reduce_kernel(expr, flat, n_valid_cols, kind,
-                              block_r=block_r, interpret=interpret)
+                              block_r=block_r)
     return out.reshape(lead)

@@ -7,6 +7,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import pallas_call
+
 __all__ = ["layernorm_kernel"]
 
 
@@ -22,11 +24,10 @@ def _body(x_ref, g_ref, b_ref, o_ref, *, eps: float):
 
 
 def layernorm_kernel(x: jax.Array, g: jax.Array, b: jax.Array, *,
-                     eps: float = 1e-5, block_r: int = 8,
-                     interpret: bool = True) -> jax.Array:
+                     eps: float = 1e-5, block_r: int = 8) -> jax.Array:
     r, d = x.shape
     assert r % block_r == 0, (r, block_r)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_body, eps=eps),
         grid=(r // block_r,),
         in_specs=[
@@ -36,5 +37,4 @@ def layernorm_kernel(x: jax.Array, g: jax.Array, b: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_r, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
-        interpret=interpret,
     )(x, g.reshape(1, d), b.reshape(1, d))

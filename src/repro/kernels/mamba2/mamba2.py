@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call
+
 __all__ = ["mamba2_kernel"]
 
 
@@ -65,8 +67,7 @@ def _body(x_ref, a_ref, b_ref, c_ref, o_ref, h_scr, *, chunk: int):
     h_scr[...] = h_new
 
 
-def mamba2_kernel(x, a, b, c, *, chunk: int = 16,
-                  interpret: bool = True) -> jax.Array:
+def mamba2_kernel(x, a, b, c, *, chunk: int = 16) -> jax.Array:
     """x: (B,H,T,P); a: (B,H,T,1); b,c: (B,H,T,N).  Returns (B,H,T,P)."""
     bsz, h, t, p = x.shape
     n = b.shape[-1]
@@ -75,12 +76,11 @@ def mamba2_kernel(x, a, b, c, *, chunk: int = 16,
     spec_x = pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, ic: (b_, h_, ic, 0))
     spec_a = pl.BlockSpec((1, 1, chunk, 1), lambda b_, h_, ic: (b_, h_, ic, 0))
     spec_bn = pl.BlockSpec((1, 1, chunk, n), lambda b_, h_, ic: (b_, h_, ic, 0))
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_body, chunk=chunk),
         grid=grid,
         in_specs=[spec_x, spec_a, spec_bn, spec_bn],
         out_specs=spec_x,
         out_shape=jax.ShapeDtypeStruct((bsz, h, t, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        interpret=interpret,
     )(x, a, b, c)

@@ -9,11 +9,11 @@ from .mamba2 import mamba2_kernel
 CHUNK_VERSIONS = (16, 64, 128)
 
 
-def mamba2_scan(x, a, b, c, *, interpret: bool = True) -> jax.Array:
+def mamba2_scan(x, a, b, c) -> jax.Array:
     t = x.shape[2]
     fits = [ck for ck in CHUNK_VERSIONS if t % ck == 0]
     if fits:
-        return mamba2_kernel(x, a, b, c, chunk=max(fits), interpret=interpret)
+        return mamba2_kernel(x, a, b, c, chunk=max(fits))
     ck = CHUNK_VERSIONS[0]
     pad = (-t) % ck
     pads = ((0, 0), (0, 0), (0, pad), (0, 0))
@@ -21,5 +21,5 @@ def mamba2_scan(x, a, b, c, *, interpret: bool = True) -> jax.Array:
         jnp.pad(x, pads),
         jnp.pad(a, pads, constant_values=1.0),  # identity decay in padding
         jnp.pad(b, pads), jnp.pad(c, pads),
-        chunk=ck, interpret=interpret)
+        chunk=ck)
     return out[:, :, :t]

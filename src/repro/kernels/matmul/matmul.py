@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call, widen
+
 __all__ = ["matmul_kernel", "matmul_epilogue_kernel"]
 
 
@@ -52,14 +54,13 @@ def _body(a_ref, b_ref, o_ref, acc_ref):
 
 
 def matmul_kernel(a: jax.Array, b: jax.Array, *, block_m: int = 128,
-                  block_k: int = 128, block_n: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  block_k: int = 128, block_n: int = 128) -> jax.Array:
     m, k = a.shape
     k2, n = b.shape
     assert k == k2
     assert m % block_m == 0 and k % block_k == 0 and n % block_n == 0
     grid = (m // block_m, n // block_n, k // block_k)
-    return pl.pallas_call(
+    return pallas_call(
         _body,
         grid=grid,
         in_specs=[
@@ -69,7 +70,6 @@ def matmul_kernel(a: jax.Array, b: jax.Array, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
     )(a, b)
 
 
@@ -103,8 +103,9 @@ def _fused_body(epilogue, n_extra: int, n_out: int, acc_dtype):
             col = (jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
                    + jn * bn)
             mask = (row < lens_ref[0]) & (col < lens_ref[1])  # M/N tails
-            ys = epilogue(acc_ref[...].astype(acc_dtype),
-                          *[r[...] for r in extra_refs])
+            # rounded to the dot's dtype, then widened for the epilogue
+            ys = epilogue(widen(acc_ref[...].astype(acc_dtype)),
+                          *[widen(r[...]) for r in extra_refs])
             if not isinstance(ys, (tuple, list)):
                 ys = (ys,)
             for r, y in zip(out_refs, ys):
@@ -115,8 +116,7 @@ def _fused_body(epilogue, n_extra: int, n_out: int, acc_dtype):
 
 def matmul_epilogue_kernel(a, b, extras, epilogue, valid_mnk, out_dtypes,
                            *, acc_dtype=jnp.float32, block_m: int = 128,
-                           block_k: int = 128, block_n: int = 128,
-                           interpret: bool = True):
+                           block_k: int = 128, block_n: int = 128):
     """Blocked GEMM with a fused elementwise epilogue and masked tails.
 
     ``extras`` are (M, N) operands the epilogue consumes alongside the
@@ -132,7 +132,7 @@ def matmul_epilogue_kernel(a, b, extras, epilogue, valid_mnk, out_dtypes,
     assert all(x.shape == (m, n) for x in extras), "extras must be (M, N)"
     grid = (m // block_m, n // block_n, k // block_k)
     mn_spec = pl.BlockSpec((block_m, block_n), lambda i, j, kk, s: (i, j))
-    return pl.pallas_call(
+    return pallas_call(
         _fused_body(epilogue, len(extras), len(out_dtypes), acc_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -145,6 +145,5 @@ def matmul_epilogue_kernel(a, b, extras, epilogue, valid_mnk, out_dtypes,
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((m, n), dt) for dt in out_dtypes],
-        interpret=interpret,
     )(jnp.asarray(jnp.stack([jnp.asarray(v, jnp.int32) for v in valid_mnk])),
       a, b, *extras)

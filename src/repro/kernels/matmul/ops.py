@@ -49,8 +49,8 @@ def select_gemm_version(m: int, k: int, n: int) -> Optional[str]:
     return None  # vendor library (XLA dot)
 
 
-def matmul(a: jax.Array, b: jax.Array, *, version: Optional[str] = None,
-           interpret: bool = True) -> jax.Array:
+def matmul(a: jax.Array, b: jax.Array, *,
+           version: Optional[str] = None) -> jax.Array:
     m, k = a.shape
     _, n = b.shape
     if version is None:
@@ -58,15 +58,18 @@ def matmul(a: jax.Array, b: jax.Array, *, version: Optional[str] = None,
     if version is None:
         return jnp.dot(a, b)  # vendor entry
     bm, bk, bn = GEMM_LIBRARY[version]
-    return matmul_kernel(a, b, block_m=bm, block_k=bk, block_n=bn,
-                         interpret=interpret)
+    return matmul_kernel(a, b, block_m=bm, block_k=bk, block_n=bn)
 
 
 # block-size preference ladders for the fused (kDot) entry: the largest
-# aligned version wins; misaligned sizes are padded up to the smallest
+# aligned version wins; misaligned sizes are padded up to the smallest.
+# M is only ever a sublane (second-to-last) block dim, which Mosaic takes
+# in multiples of 8; N and K are the lane (last) dim of some operand's
+# block, which it takes in multiples of 128 or as the whole dim.
 _FUSED_M_BLOCKS = (128, 64, 32, 16, 8)
-_FUSED_N_BLOCKS = (128, 64, 32, 16, 8)
-_FUSED_K_BLOCKS = (512, 256, 128, 64, 32, 16, 8)
+_FUSED_N_BLOCKS = (128,)
+_FUSED_K_BLOCKS = (512, 256, 128)
+_LANES = 128
 
 
 def _pick_block(size: int, prefs: Tuple[int, ...]) -> Tuple[int, int]:
@@ -79,9 +82,17 @@ def _pick_block(size: int, prefs: Tuple[int, ...]) -> Tuple[int, int]:
     return b, ((size + b - 1) // b) * b
 
 
+def _pick_lane_block(size: int, prefs: Tuple[int, ...]) -> Tuple[int, int]:
+    """:func:`_pick_block` for a lane dim: a dim narrower than one lane
+    tile is a single block spanning the whole dim, unpadded."""
+    if size < _LANES:
+        return size, size
+    return _pick_block(size, prefs)
+
+
 def matmul_fused(a: jax.Array, b: jax.Array, extras: Sequence[jax.Array],
                  epilogue: Callable, *, valid_mnk, out_dtypes: Sequence,
-                 acc_dtype=None, interpret: bool = True) -> List[jax.Array]:
+                 acc_dtype=None) -> List[jax.Array]:
     """(M, K) @ (K, N) with a fused elementwise epilogue (kDot).
 
     ``extras`` are (M, N) epilogue operands; ``valid_mnk`` the runtime
@@ -92,8 +103,8 @@ def matmul_fused(a: jax.Array, b: jax.Array, extras: Sequence[jax.Array],
     k2, n = b.shape
     assert k == k2
     bm, pm = _pick_block(m, _FUSED_M_BLOCKS)
-    bn, pn = _pick_block(n, _FUSED_N_BLOCKS)
-    bk, pk = _pick_block(k, _FUSED_K_BLOCKS)
+    bn, pn = _pick_lane_block(n, _FUSED_N_BLOCKS)
+    bk, pk = _pick_lane_block(k, _FUSED_K_BLOCKS)
 
     def pad2(x, rows, cols):
         pr, pc = rows - x.shape[0], cols - x.shape[1]
@@ -105,7 +116,7 @@ def matmul_fused(a: jax.Array, b: jax.Array, extras: Sequence[jax.Array],
     outs = matmul_epilogue_kernel(
         a, b, extras, epilogue, valid_mnk, list(out_dtypes),
         acc_dtype=acc_dtype if acc_dtype is not None else jnp.float32,
-        block_m=bm, block_k=bk, block_n=bn, interpret=interpret)
+        block_m=bm, block_k=bk, block_n=bn)
     if (pm, pn) != (m, n):
         outs = [o[:m, :n] for o in outs]
     return list(outs)

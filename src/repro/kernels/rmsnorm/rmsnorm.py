@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import pallas_call
+
 __all__ = ["rmsnorm_kernel"]
 
 
@@ -24,11 +26,11 @@ def _body(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_kernel(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
-                   block_r: int = 8, interpret: bool = True) -> jax.Array:
+                   block_r: int = 8) -> jax.Array:
     r, d = x.shape
     assert r % block_r == 0, (r, block_r)
     import functools
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_body, eps=eps),
         grid=(r // block_r,),
         in_specs=[
@@ -37,5 +39,4 @@ def rmsnorm_kernel(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_r, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
-        interpret=interpret,
     )(x, w.reshape(1, d))

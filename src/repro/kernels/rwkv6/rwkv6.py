@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call
+
 __all__ = ["rwkv6_kernel"]
 
 
@@ -57,8 +59,7 @@ def _body(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, chunk: int):
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
-def rwkv6_kernel(r, k, v, w, u, *, chunk: int = 16,
-                 interpret: bool = True) -> jax.Array:
+def rwkv6_kernel(r, k, v, w, u, *, chunk: int = 16) -> jax.Array:
     """r,k,w: (B,H,T,K); v: (B,H,T,V); u: (H,K). Returns (B,H,T,V)."""
     b, h, t, dk = r.shape
     dv = v.shape[-1]
@@ -67,12 +68,11 @@ def rwkv6_kernel(r, k, v, w, u, *, chunk: int = 16,
     spec_k = pl.BlockSpec((1, 1, chunk, dk), lambda b_, h_, c: (b_, h_, c, 0))
     spec_v = pl.BlockSpec((1, 1, chunk, dv), lambda b_, h_, c: (b_, h_, c, 0))
     spec_u = pl.BlockSpec((1, dk), lambda b_, h_, c: (h_, 0))
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_body, chunk=chunk),
         grid=grid,
         in_specs=[spec_k, spec_k, spec_v, spec_k, spec_u],
         out_specs=spec_v,
         out_shape=jax.ShapeDtypeStruct((b, h, t, dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
     )(r, k, v, w, u)

@@ -10,7 +10,7 @@ ROW_VERSIONS = (8, 64, 256)
 _VMEM_BUDGET = 4 * 1024 * 1024
 
 
-def masked_softmax(x: jax.Array, n_valid, *, interpret: bool = True):
+def masked_softmax(x: jax.Array, n_valid):
     """Softmax over the last axis with dynamic valid length (leading dims
     flattened into rows)."""
     lead = x.shape[:-1]
@@ -21,12 +21,11 @@ def masked_softmax(x: jax.Array, n_valid, *, interpret: bool = True):
     fits = [b for b in ROW_VERSIONS
             if r % b == 0 and b * c * item <= _VMEM_BUDGET]
     if fits:
-        out = masked_softmax_kernel(flat, n_valid, block_r=max(fits),
-                                    interpret=interpret)
+        out = masked_softmax_kernel(flat, n_valid, block_r=max(fits))
     else:
         b = ROW_VERSIONS[0]
         pad = (-r) % b
         out = masked_softmax_kernel(jnp.pad(flat, ((0, pad), (0, 0))),
-                                    n_valid, block_r=b, interpret=interpret)
+                                    n_valid, block_r=b)
         out = out[:r]
     return out.reshape(*lead, c)

@@ -13,6 +13,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import pallas_call
+
 __all__ = ["masked_softmax_kernel"]
 
 
@@ -34,13 +36,13 @@ def _body(len_ref, x_ref, o_ref):
     o_ref[...] = e / s
 
 
-def masked_softmax_kernel(x: jax.Array, n_valid, *, block_r: int = 8,
-                          interpret: bool = True) -> jax.Array:
+def masked_softmax_kernel(x: jax.Array, n_valid, *,
+                          block_r: int = 8) -> jax.Array:
     """Softmax over axis 1 of (R, C) with valid length ``n_valid``."""
     r, c = x.shape
     assert r % block_r == 0, (r, block_r)
     spec = pl.BlockSpec((block_r, c), lambda i, s: (i, 0))
-    return pl.pallas_call(
+    return pallas_call(
         _body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -49,5 +51,4 @@ def masked_softmax_kernel(x: jax.Array, n_valid, *, block_r: int = 8,
             out_specs=spec,
         ),
         out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
-        interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1), x)

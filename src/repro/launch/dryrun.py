@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture x input-shape x mesh) cell on the production mesh and dump
 memory/cost/roofline analysis.
 
-The two lines above MUST stay the first statements in this file — jax
-locks the device count on first init.
+The forced host device count below is added to the caller's
+``XLA_FLAGS`` before jax is imported — jax locks the device count on
+first init.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama_11b \
@@ -15,6 +13,13 @@ Usage:
 
 Results land in reports/dryrun/<mesh>/<arch>__<cell>.json plus stdout.
 """
+import os
+
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=512").strip()
+
 import argparse
 import json
 import pathlib
@@ -37,6 +42,9 @@ from .mesh import make_production_mesh
 from .shapes import SHAPE_CELLS, cells_for_arch, input_specs
 
 REPORT_DIR = pathlib.Path(__file__).resolve().parents[3] / "reports" / "dryrun"
+# the host devices of the production mesh stand in for a TPU v5e pod:
+# roofline terms use that chip's peaks
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 # spec fitting (drop axes that don't divide the dim) lives in
@@ -141,7 +149,8 @@ def lower_cell(arch_id: str, cell_name: str, *, multi_pod: bool,
     mem = compiled.memory_analysis()
     terms = analyze_compiled(compiled, arch=arch_id, cell=cell_name,
                              mesh_name=mesh_name, chips=chips,
-                             model_flops=_model_flops(cfg, cell))
+                             model_flops=_model_flops(cfg, cell),
+                             device_kind=TARGET_DEVICE_KIND)
     result = terms.as_dict()
     result.update({
         "lower_seconds": round(t_lower, 2),

@@ -7,10 +7,12 @@
 """
 import argparse
 import dataclasses
+import sys
 
 import jax
 
 from ..api import ServeConfig, ServeEngine
+from ..api.persistent_cache import enable_persistent_cache
 from ..configs import ARCH_IDS, get_config
 from ..obs.clock import CLOCK as _clock
 from ..data.pipeline import VarLenRequestStream
@@ -32,6 +34,10 @@ def main():
         lower_cell(args.arch, "decode_32k", multi_pod=False)
         return
 
+    enable_persistent_cache()
+    dev = jax.devices()[0]
+    print(f"serving on {dev.platform} ({dev.device_kind}) "
+          f"x{len(jax.devices())}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(cfg.reduced(), max_seq=args.max_seq)
@@ -50,6 +56,10 @@ def main():
     print(f"{len(done)}/{args.requests} requests in {dt:.1f}s; "
           f"{engine.stats['tokens_generated']} tokens; "
           f"prefill compiles {engine.stats['prefill_compiles']}")
+    if engine.failed or engine.rejected or len(done) != args.requests:
+        print(f"failed: {engine.failed}; rejected: {engine.rejected}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -663,16 +663,15 @@ def moe_apply(cfg: ArchConfig, p: Params, x: jax.Array) -> jax.Array:
             # each token's k experts may live on different EP ranks
             return jax.lax.psum(y, "model")
 
-        from jax.experimental.shard_map import shard_map
         dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         tok_spec = P(dp_axes if dp_axes else None, None)
-        y = shard_map(
+        y = jax.shard_map(
             ep_body, mesh=mesh,
             in_specs=(P("model", None, None), P("model", None, None),
                       P("model", None, None),
                       tok_spec, tok_spec, tok_spec),
             out_specs=tok_spec,
-            check_rep=False,
+            check_vma=False,
         )(p["w_in"], p["w_gate"], p["w_out"], tokens, gates, ids)
     else:
         cap = int(cfg.capacity_factor * t * cfg.top_k / max(e, 1))

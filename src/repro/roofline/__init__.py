@@ -1,1 +1,2 @@
-from .analysis import RooflineTerms, analyze_compiled, HW  # noqa: F401
+from .analysis import (PEAKS, Peaks, RooflineTerms,  # noqa: F401
+                       analyze_compiled, peaks_for)

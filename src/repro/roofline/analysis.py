@@ -7,7 +7,8 @@
 Sources: ``compiled.cost_analysis()`` for FLOPs/bytes; ``compiled.as_text()``
 parsed for all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute operand bytes (collective bytes are NOT in
-cost_analysis).  Hardware constants: TPU v5e.
+cost_analysis).  Hardware peaks come from :data:`PEAKS`, keyed by
+``jax.Device.device_kind``.
 """
 from __future__ import annotations
 
@@ -15,13 +16,36 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
-__all__ = ["HW", "RooflineTerms", "analyze_compiled", "collective_bytes"]
+__all__ = ["Peaks", "PEAKS", "peaks_for", "RooflineTerms",
+           "analyze_compiled", "collective_bytes"]
 
 
-class HW:
-    PEAK_FLOPS_BF16 = 197e12      # per chip
-    HBM_BW = 819e9                # bytes/s per chip
-    ICI_LINK_BW = 50e9            # bytes/s per link
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float             # FLOP/s
+    hbm_bw: float                 # bytes/s
+    ici_link_bw: float            # bytes/s per chip-to-chip link
+
+
+#: per-chip peaks keyed by ``device_kind``.  TPU v5e: Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+#: of chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops_bf16=197e12, hbm_bw=819e9, ici_link_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a kind not in :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known "
+            f"kinds: {sorted(PEAKS)}") from None
 
 
 _DTYPE_BYTES = {
@@ -93,20 +117,21 @@ class RooflineTerms:
     coll_bytes: float
     coll_breakdown: Dict[str, int]
     model_flops: float
+    peaks: Peaks
     bytes_per_device: float = 0.0
     peak_memory_per_device: float = 0.0
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / (self.chips * HW.PEAK_FLOPS_BF16)
+        return self.hlo_flops / (self.chips * self.peaks.flops_bf16)
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / (self.chips * HW.HBM_BW)
+        return self.hlo_bytes / (self.chips * self.peaks.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / (self.chips * HW.ICI_LINK_BW)
+        return self.coll_bytes / (self.chips * self.peaks.ici_link_bw)
 
     @property
     def dominant(self) -> str:
@@ -123,7 +148,7 @@ class RooflineTerms:
         """Fraction of the binding roofline the useful work achieves:
         t_model_compute / max(all terms) — 1.0 means the dominant term is
         exactly the useful compute."""
-        t_model = self.model_flops / (self.chips * HW.PEAK_FLOPS_BF16)
+        t_model = self.model_flops / (self.chips * self.peaks.flops_bf16)
         bound = max(self.t_compute, self.t_memory, self.t_collective)
         return t_model / bound if bound else 0.0
 
@@ -146,8 +171,10 @@ class RooflineTerms:
 
 
 def analyze_compiled(compiled, *, arch: str, cell: str, mesh_name: str,
-                     chips: int, model_flops: float) -> RooflineTerms:
-    """Roofline terms from the compiled artifact.
+                     chips: int, model_flops: float,
+                     device_kind: str) -> RooflineTerms:
+    """Roofline terms from the compiled artifact, against the peaks of
+    ``device_kind`` (the chip the program is compiled for).
 
     Primary source: our trip-count-aware HLO walk (hlo_cost.py) — XLA's
     cost_analysis counts while bodies once, which under-reports scanned
@@ -189,6 +216,7 @@ def analyze_compiled(compiled, *, arch: str, cell: str, mesh_name: str,
         arch=arch, cell=cell, mesh=mesh_name, chips=chips,
         hlo_flops=flops, hlo_bytes=bts, coll_bytes=total_coll,
         coll_breakdown=coll, model_flops=model_flops,
+        peaks=peaks_for(device_kind),
         bytes_per_device=per_dev,
         peak_memory_per_device=per_dev,
     )

@@ -323,7 +323,9 @@ class TestShardedDispatchParity:
             out = np.asarray(fn(w1, w2, x))
             if ref is None:
                 ref = out
-            np.testing.assert_allclose(out, ref, atol=1e-6)
+            # the third call runs the escalated exact-shape program, which
+            # sums in a different order than the padded-bucket program
+            np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
         assert fn.compile_counts()["exact"] == 1
         assert fn.cache_stats()["escalations"] == 1
 

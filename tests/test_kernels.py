@@ -1,4 +1,4 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracles.
+"""Per-kernel validation: Pallas (interpreted on the CPU) vs pure-jnp oracles.
 
 Sweeps shapes and dtypes per kernel; every kernel must match its ref.py
 oracle within per-dtype tolerances.
@@ -20,7 +20,8 @@ from repro.kernels.layernorm.ops import layernorm
 from repro.kernels.layernorm.ref import layernorm_ref
 from repro.kernels.flash_attention.ops import flash_attention, flash_decode
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.matmul.ops import matmul, select_gemm_version
+from repro.kernels.matmul.ops import (matmul, matmul_fused,
+                                      select_gemm_version)
 from repro.kernels.matmul.ref import matmul_ref
 from repro.kernels.rwkv6.ops import rwkv6_scan
 from repro.kernels.rwkv6.ref import rwkv6_ref
@@ -203,6 +204,21 @@ class TestMatmulLibrary:
                                    np.asarray(want, np.float32),
                                    rtol=3e-2 if dtype == jnp.bfloat16 else 1e-4,
                                    atol=3e-1 if dtype == jnp.bfloat16 else 1e-3)
+
+    @pytest.mark.parametrize("mkn", [(128, 128, 192), (128, 96, 128),
+                                     (200, 64, 100), (7, 300, 130)])
+    def test_fused_off_lane_tiling(self, mkn):
+        # N/K that are no multiple of 128 are padded (or taken whole) and
+        # the tails masked: the result is the plain epilogue(a @ b)
+        m, k, n = mkn
+        rng = np.random.RandomState(13)
+        a = _rand(rng, (m, k), jnp.float32)
+        b = _rand(rng, (k, n), jnp.float32)
+        r = _rand(rng, (m, n), jnp.float32)
+        got, = matmul_fused(a, b, [r], lambda acc, res: jax.nn.silu(acc) + res,
+                            valid_mnk=(m, n, k), out_dtypes=[jnp.float32])
+        want = jax.nn.silu(matmul_ref(a, b)) + r
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
     def test_selection_interface(self):
         assert select_gemm_version(2048, 1024, 2048) == "square_big"

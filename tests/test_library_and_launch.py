@@ -1,7 +1,10 @@
 """§4.5 library interface + launcher smoke coverage."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.api import persistent_cache
 from repro.core.library import pick
 
 
@@ -23,3 +26,25 @@ class TestLibrary:
 
     def test_decode_shape_routes_to_skinny(self):
         assert pick(8, 128, 128).name == "library:skinny_m"
+
+
+class TestPersistentCache:
+    @pytest.fixture
+    def cache_dir_restored(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, cache_dir_restored):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert persistent_cache.enable_persistent_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_fixed_dir_in_the_checkout(self, monkeypatch, cache_dir_restored):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        got = persistent_cache.enable_persistent_cache()
+        assert got == str(persistent_cache.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == got
+        assert persistent_cache.CACHE_DIR.parent.joinpath(
+            "chip_smoke.py").exists()

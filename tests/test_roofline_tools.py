@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.roofline.analysis import peaks_for
 from repro.roofline.hlo_cost import analyze_hlo_text
 
 
@@ -70,3 +71,13 @@ class TestHloCost:
         cost = analyze_hlo_text(_compiled_text(f, sds))
         # boundary traffic ~ in + out (not 4 tensors worth)
         assert cost.bytes <= 4 * 4096 * 4
+
+
+class TestPeaks:
+    def test_v5e_published_peaks(self):
+        peaks = peaks_for("TPU v5 lite")
+        assert peaks.flops_bf16 == 197e12 and peaks.hbm_bw == 819e9
+
+    def test_unknown_device_kind_is_an_error(self):
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks_for("cpu")
