@@ -1,0 +1,10 @@
+"""Device: the share of the traced window in which no operation ran on
+the device (one minus the union of operation intervals over the
+window)."""
+
+
+def read(run):
+    tr = run.get("trace")
+    if not tr or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
