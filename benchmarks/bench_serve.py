@@ -61,6 +61,7 @@ from disc import FaultInjector, ServeConfig, ServeEngine, faults
 from repro.configs import get_config
 from repro.data.pipeline import Request, VarLenRequestStream
 from repro.models.registry import get_model
+from repro.obs import trace as obs_trace
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 
@@ -120,6 +121,16 @@ def _run_trace(eng, reqs, max_steps=50_000) -> Dict[int, float]:
     return lat
 
 
+def _max_decode_gap(events) -> float:
+    """Decode stall: the longest gap between the starts of consecutive
+    ``serve.decode_step`` spans that share a request (one waiting on its
+    next token across the gap)."""
+    steps = [e for e in events if e["name"] == "serve.decode_step"]
+    return max((b["ts"] - a["ts"] for a, b in zip(steps, steps[1:])
+                if set(a["args"]["rids"]) & set(b["args"]["rids"])),
+               default=0.0)
+
+
 def _measure(model, params, scfg, reqs_fn) -> Dict:
     """Warmup pass (compiles), then a measured pass over the same trace."""
     eng = ServeEngine(model, params, scfg)
@@ -135,14 +146,18 @@ def _measure(model, params, scfg, reqs_fn) -> Dict:
         eng.done.clear()  # every pass reuses the same trace rids
     warm_compiles = eng.stats["prefill_compiles"]
     eng.reset_stats()
-    lat = _run_trace(eng, reqs_fn())
+    # the decode stall is read off the measured pass's decode spans (into
+    # the run-wide tracer under --trace)
+    with obs_trace.tracing(obs_trace.ACTIVE) as tr:
+        n0 = len(tr.events)
+        lat = _run_trace(eng, reqs_fn())
     st = eng.stats
     vals = sorted(lat.values())
     return {
         "tokens_per_sec": round(st["tokens_per_sec"], 1),
         "p50_latency_s": round(float(np.percentile(vals, 50)), 4),
         "p99_latency_s": round(float(np.percentile(vals, 99)), 4),
-        "max_decode_gap_s": round(st["max_decode_gap_s"], 4),
+        "max_decode_gap_s": round(_max_decode_gap(tr.events[n0:]), 4),
         "prefill_calls": st["prefill_calls"],
         "batched_prefills": st["batched_prefills"],
         "prefill_chunks": st["prefill_chunks"],

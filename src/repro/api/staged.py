@@ -42,7 +42,7 @@ import jax
 import numpy as np
 
 from ..core.bucketing import BucketPolicy
-from ..core.cache import CompileCache
+from ..core.cache import CompileCache, at_first_call
 from ..errors import CONTROL_EXCEPTIONS, CompileError, classify_transient
 from ..core.codegen import dyn_symbols
 from ..core.dispatcher import dhlo_lens, generate_dispatch, jit_lens
@@ -577,12 +577,15 @@ class Compiled:
                                         self.lowered.plan)
 
     # ----------------------------------------------------- jit pipeline --
+    @at_first_call
     def _compile_jit_bucket(self, key: Tuple[int, ...]):
         """One ``jax.jit`` entry per bucket signature: the dispatch pads
-        dynamic args to the bucket, so the entry traces exactly once."""
+        dynamic args to the bucket, so the entry traces exactly once, at
+        its first call (which the cache times as the compile)."""
         self._bucket_compiles += 1
         return jax.jit(self.lowered.fn)
 
+    @at_first_call
     def _compile_jit_exact(self):
         """§4.4 for the jit pipeline: the escalated path calls the
         function at *unpadded* shapes, so hot shapes get a mask/padding-

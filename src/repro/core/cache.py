@@ -9,13 +9,23 @@ count is O(#buckets), not O(#shapes).
 Also implements §4.4's static/dynamic mix: signatures that stay hot are
 *escalated* to exact-shape static specializations (better codegen: no
 masking, no padding waste), bounded by an LRU budget.
+
+A compile is timed where it happens.  An ahead-of-time build compiles
+inside ``compile_fn``.  A ``jax.jit`` wrapper (the ``jit`` pipeline)
+traces, then compiles or loads from JAX's persistent cache, at its first
+call: a compile function marked :func:`at_first_call` has its entry's
+first call timed instead, and the bare wrapper takes the entry's place
+after it.  The ``compile.bucket`` / ``compile.exact`` span,
+``stats.compile_seconds`` and the timeline event cover the compile
+either way, with JAX's own compile events inside it summed onto the
+span.
 """
 from __future__ import annotations
 
 import time
 from collections import OrderedDict
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Optional, Set, Tuple
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 from ..errors import (CONTROL_EXCEPTIONS, DEFAULT_RETRY, RetryPolicy,
                       wrap_compile_error)
@@ -23,13 +33,59 @@ from ..ft import faults
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 
-__all__ = ["CompileCache", "CacheStats"]
+__all__ = ["CompileCache", "CacheStats", "at_first_call"]
+
+# JAX's compile events (``jax.monitoring``): the seconds of each stage of
+# obtaining an executable, by the span argument that sums them
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    # an XLA compile, or a load from the persistent compilation cache
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+}
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+# one accumulator per open compile span, innermost last
+_open_compiles: List[Dict[str, Any]] = []
+_listening = False
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    if _open_compiles and event in _JAX_STAGES:
+        _open_compiles[-1][_JAX_STAGES[event]] += duration
+
+
+def _on_jax_event(event: str, **kw) -> None:
+    if _open_compiles and event == _JAX_CACHE_HIT:
+        _open_compiles[-1]["persistent_cache_hit"] = True
+
+
+def _listen() -> None:
+    """Register the JAX compile-event listeners, once per process, at the
+    first compile (``jax.monitoring`` has no public unregister); outside
+    a compile each event costs one empty-list test."""
+    global _listening
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        jax.monitoring.register_event_listener(_on_jax_event)
+        _listening = True
+
+
+def at_first_call(compile_fn: Callable) -> Callable:
+    """Mark a compile function whose entry compiles at its first call (it
+    returns a ``jax.jit`` wrapper): the cache times that call."""
+    compile_fn.at_first_call = True
+    return compile_fn
 
 
 @dataclass
 class CacheStats:
     hits: int = 0
     misses: int = 0
+    # seconds spent obtaining executables: tracing, lowering, and the XLA
+    # compile or the load from JAX's persistent cache
     compile_seconds: float = 0.0
     escalations: int = 0
     evictions: int = 0
@@ -106,6 +162,54 @@ class CompileCache:
                 time.sleep(self.retry_policy.delay(attempt))
                 attempt += 1
 
+    def _timed(self, kind: str, sig: Tuple, fp: str,
+               run: Callable[[], Any]) -> Any:
+        """Run one ``kind`` ("bucket" or "exact") compile under its span,
+        its seconds counted in ``stats`` and journaled on the timeline."""
+        _listen()
+        acc: Dict[str, Any] = dict.fromkeys(_JAX_STAGES.values(), 0.0)
+        acc["persistent_cache_hit"] = False
+        sp = (obs_trace.ACTIVE.begin(f"compile.{kind}", cat="compile",
+                                     key=str(sig),
+                                     artifact=fp[:40], cache_hit=False)
+              if obs_trace.ACTIVE is not None else None)
+        _open_compiles.append(acc)
+        t0 = time.perf_counter()
+        try:
+            out = run()
+        finally:
+            dt = time.perf_counter() - t0
+            _open_compiles.remove(acc)
+            self.stats.compile_seconds += dt
+            if sp is not None:
+                sp.end(**acc)
+        obs_metrics.record_event(
+            "compile.bucket" if kind == "bucket" else "escalate",
+            key=str(sig), artifact=fp[:40], seconds=round(dt, 4))
+        return out
+
+    def _obtain(self, key: Tuple, kind: str, sig: Tuple, fp: str,
+                compile_fn: Callable[[], Any]) -> Any:
+        """The entry for a missed ``key``: compiled now, or, where
+        ``compile_fn`` is marked :func:`at_first_call`, an entry whose
+        first call is timed as the compile."""
+        # the fault-site key carries the artifact fingerprint so an
+        # injector can target one artifact (match="prefill") of a shared
+        # cache
+        what, site = f"{fp} {kind} {sig}", f"compile.{kind}"
+        if not getattr(compile_fn, "at_first_call", False):
+            return self._timed(kind, sig, fp, lambda: self._compile_with_retry(
+                compile_fn, what, site))
+        fn = self._compile_with_retry(compile_fn, what, site)
+
+        def first_call(*args):
+            out = self._timed(kind, sig, fp, lambda: fn(*args))
+            if self._entries.get(key) is first_call:
+                self._entries[key] = fn
+            return out
+
+        return first_call
+
     # --------------------------------------------------------- bucketed --
     def get_or_compile(self, bucket_sig: Tuple, compile_fn: Callable[[], Any],
                        fingerprint: Optional[str] = None) -> Any:
@@ -123,25 +227,8 @@ class CompileCache:
             self._entries.move_to_end(key)
             return entry
         self.stats.misses += 1
-        fp = fingerprint or self.fingerprint
-        sp = (obs_trace.ACTIVE.begin("compile.bucket", cat="compile",
-                                     key=str(bucket_sig), artifact=fp[:40],
-                                     cache_hit=False)
-              if obs_trace.ACTIVE is not None else None)
-        t0 = time.perf_counter()
-        try:
-            # the fault-site key carries the artifact fingerprint so an
-            # injector can target one artifact (match="prefill") of a
-            # shared cache
-            entry = self._compile_with_retry(
-                compile_fn, f"{fp} bucket {bucket_sig}", "compile.bucket")
-        finally:
-            dt = time.perf_counter() - t0
-            self.stats.compile_seconds += dt
-            if sp is not None:
-                sp.end()
-        obs_metrics.record_event("compile.bucket", key=str(bucket_sig),
-                                 artifact=fp[:40], seconds=round(dt, 4))
+        entry = self._obtain(key, "bucket", bucket_sig,
+                             fingerprint or self.fingerprint, compile_fn)
         self._entries[key] = entry
         self._evict()
         return entry
@@ -183,22 +270,8 @@ class CompileCache:
             return entry
         self.stats.misses += 1
         self.stats.escalations += 1
-        fp = fingerprint or self.fingerprint
-        sp = (obs_trace.ACTIVE.begin("compile.exact", cat="compile",
-                                     key=str(exact_sig), artifact=fp[:40],
-                                     cache_hit=False)
-              if obs_trace.ACTIVE is not None else None)
-        t0 = time.perf_counter()
-        try:
-            entry = self._compile_with_retry(
-                compile_fn, f"{fp} exact {exact_sig}", "compile.exact")
-        finally:
-            dt = time.perf_counter() - t0
-            self.stats.compile_seconds += dt
-            if sp is not None:
-                sp.end()
-        obs_metrics.record_event("escalate", key=str(exact_sig),
-                                 artifact=fp[:40], seconds=round(dt, 4))
+        entry = self._obtain(key, "exact", exact_sig,
+                             fingerprint or self.fingerprint, compile_fn)
         self._entries[key] = entry
         self._evict()
         return entry

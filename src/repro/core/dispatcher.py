@@ -757,8 +757,11 @@ def generate_dispatch(
         return e
 
     def _compile(key):
-        return cache.get_or_compile(key, lambda: compile_bucket(key),
-                                    fingerprint=fingerprint)
+        def build():
+            return compile_bucket(key)
+
+        build.at_first_call = getattr(compile_bucket, "at_first_call", False)
+        return cache.get_or_compile(key, build, fingerprint=fingerprint)
 
     ns["_get"] = _get
     ns["_compile"] = _compile

@@ -75,16 +75,17 @@ def norm_specs(cfg: ArchConfig) -> Params:
 
 
 def norm_apply(cfg: ArchConfig, p: Params, x: jax.Array) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    if cfg.norm == "layernorm":
-        mu = xf.mean(-1, keepdims=True)
-        xc = xf - mu
-        var = (xc * xc).mean(-1, keepdims=True)
-        y = xc * jax.lax.rsqrt(var + 1e-5) * p["scale"] + p["bias"]
-    else:
-        ms = (xf * xf).mean(-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + 1e-6) * p["scale"]
-    return y.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        if cfg.norm == "layernorm":
+            mu = xf.mean(-1, keepdims=True)
+            xc = xf - mu
+            var = (xc * xc).mean(-1, keepdims=True)
+            y = xc * jax.lax.rsqrt(var + 1e-5) * p["scale"] + p["bias"]
+        else:
+            ms = (xf * xf).mean(-1, keepdims=True)
+            y = xf * jax.lax.rsqrt(ms + 1e-6) * p["scale"]
+        return y.astype(x.dtype)
 
 
 # ----------------------------------------------------------------- rope --
@@ -245,57 +246,69 @@ def attn_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
     into the cache in one pass and queries attend causally against the
     whole cache at absolute positions.
 
-    ``kv_source`` enables cross-attention (whisper decoder)."""
+    ``kv_source`` enables cross-attention (whisper decoder).
+
+    Named scopes (HLO ``op_name`` metadata only) split the layer for a
+    profile: ``attn/qkv`` (projections and rotary), ``attn/cache_write``
+    (the cache update), ``attn/core`` (the attention itself) and
+    ``attn/out`` (the output projection)."""
     b, s, d = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     src = x if kv_source is None else kv_source
-    q = (x @ p["wq"]).reshape(b, s, h, hd)
-    k = (src @ p["wk"]).reshape(b, src.shape[1], hkv, hd)
-    v = (src @ p["wv"]).reshape(b, src.shape[1], hkv, hd)
-    q = maybe_shard(q, act_bsh(cfg))
-    if kv_source is None:  # self-attention: rope
-        cos, sin = rope_tables(positions, hd, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-    q = q.transpose(0, 2, 1, 3)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
+    with jax.named_scope("attn/qkv"):
+        q = (x @ p["wq"]).reshape(b, s, h, hd)
+        k = (src @ p["wk"]).reshape(b, src.shape[1], hkv, hd)
+        v = (src @ p["wv"]).reshape(b, src.shape[1], hkv, hd)
+        q = maybe_shard(q, act_bsh(cfg))
+        if kv_source is None:  # self-attention: rope
+            cos, sin = rope_tables(positions, hd, cfg.rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        q = q.transpose(0, 2, 1, 3)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
     new_cache = None
     if cache is not None and offsets is not None:
         # batched prefill: scatter the chunk's K/V to absolute positions
         # [offset, offset+len) per row — padded chunk positions are never
         # written — then attend causally against the whole cache
-        kc, vc = cache["k"], cache["v"]
-        lc = kc.shape[2]
-        j = jnp.arange(lc)[None, :] - offsets[:, None]          # (B, Lc)
-        written = (j >= 0) & (j < lens[:, None])
-        jc = jnp.clip(j, 0, s - 1)
-        idx = jnp.broadcast_to(jc[:, None, :, None], (b, hkv, lc, hd))
-        wmask = written[:, None, :, None]
-        kc = jnp.where(wmask, jnp.take_along_axis(k, idx, axis=2)
-                       .astype(kc.dtype), kc)
-        vc = jnp.where(wmask, jnp.take_along_axis(v, idx, axis=2)
-                       .astype(vc.dtype), vc)
-        new_cache = {"k": kc, "v": vc}
-        o = _sdpa(q, kc.astype(q.dtype), vc.astype(q.dtype), causal=True,
-                  lens=None, q_offset=offsets)
+        with jax.named_scope("attn/cache_write"):
+            kc, vc = cache["k"], cache["v"]
+            lc = kc.shape[2]
+            j = jnp.arange(lc)[None, :] - offsets[:, None]      # (B, Lc)
+            written = (j >= 0) & (j < lens[:, None])
+            jc = jnp.clip(j, 0, s - 1)
+            idx = jnp.broadcast_to(jc[:, None, :, None], (b, hkv, lc, hd))
+            wmask = written[:, None, :, None]
+            kc = jnp.where(wmask, jnp.take_along_axis(k, idx, axis=2)
+                           .astype(kc.dtype), kc)
+            vc = jnp.where(wmask, jnp.take_along_axis(v, idx, axis=2)
+                           .astype(vc.dtype), vc)
+            new_cache = {"k": kc, "v": vc}
+        with jax.named_scope("attn/core"):
+            o = _sdpa(q, kc.astype(q.dtype), vc.astype(q.dtype), causal=True,
+                      lens=None, q_offset=offsets)
     elif cache is not None:
         # decode: append to cache at position lens (per batch row)
-        kc, vc = cache["k"], cache["v"]
-        idx = lens[:, None, None, None]  # (B,1,1,1) write position
-        pos_iota = jnp.arange(kc.shape[2])[None, None, :, None]
-        write = pos_iota == idx
-        kc = jnp.where(write, k.astype(kc.dtype), kc)
-        vc = jnp.where(write, v.astype(vc.dtype), vc)
-        new_cache = {"k": kc, "v": vc}
-        o = _sdpa(q, kc.astype(q.dtype), vc.astype(q.dtype), causal=False,
-                  lens=lens + 1)
+        with jax.named_scope("attn/cache_write"):
+            kc, vc = cache["k"], cache["v"]
+            idx = lens[:, None, None, None]  # (B,1,1,1) write position
+            pos_iota = jnp.arange(kc.shape[2])[None, None, :, None]
+            write = pos_iota == idx
+            kc = jnp.where(write, k.astype(kc.dtype), kc)
+            vc = jnp.where(write, v.astype(vc.dtype), vc)
+            new_cache = {"k": kc, "v": vc}
+        with jax.named_scope("attn/core"):
+            o = _sdpa(q, kc.astype(q.dtype), vc.astype(q.dtype),
+                      causal=False, lens=lens + 1)
     else:
-        o = _sdpa(q, k, v, causal=causal and kv_source is None,
-                  lens=lens, q_offset=0)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    out = o @ p["wo"]
-    return maybe_shard(out, act_bsd(cfg)), new_cache
+        with jax.named_scope("attn/core"):
+            o = _sdpa(q, k, v, causal=causal and kv_source is None,
+                      lens=lens, q_offset=0)
+    with jax.named_scope("attn/out"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+        out = o @ p["wo"]
+        return maybe_shard(out, act_bsd(cfg)), new_cache
 
 
 def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int) -> Params:
@@ -541,13 +554,14 @@ def mlp_specs(cfg: ArchConfig) -> Params:
 
 
 def mlp_apply(cfg: ArchConfig, p: Params, x: jax.Array) -> jax.Array:
-    h = x @ p["w_in"]
-    if cfg.act == "silu":
-        h = jax.nn.silu(x @ p["w_gate"]) * h
-    else:
-        h = jax.nn.gelu(h)
-    h = maybe_shard(h, act_bsf(cfg))
-    return maybe_shard(h @ p["w_out"], act_bsd(cfg))
+    with jax.named_scope("mlp"):
+        h = x @ p["w_in"]
+        if cfg.act == "silu":
+            h = jax.nn.silu(x @ p["w_gate"]) * h
+        else:
+            h = jax.nn.gelu(h)
+        h = maybe_shard(h, act_bsf(cfg))
+        return maybe_shard(h @ p["w_out"], act_bsd(cfg))
 
 
 # ------------------------------------------------------------------ moe --

@@ -119,16 +119,18 @@ def _run_blocks(cfg: ArchConfig, blocks: Params, x, *, positions, lens,
 
 
 def embed_tokens(cfg: ArchConfig, params: Params, tokens) -> jax.Array:
-    x = jnp.take(params["embed"], tokens, axis=0)
-    return maybe_shard(x, L.act_bsd(cfg))
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        return maybe_shard(x, L.act_bsd(cfg))
 
 
 def logits_from_hidden(cfg: ArchConfig, params: Params, x) -> jax.Array:
-    head = params["embed"].T if cfg.tie_embeddings else params["head"]
-    logits = x @ head
-    spec = (P(L._DP_ALL, None, None) if cfg.sharding_profile == "fsdp"
-            else P(("pod", "data"), None, "model"))
-    return maybe_shard(logits, spec)
+    with jax.named_scope("head"):
+        head = params["embed"].T if cfg.tie_embeddings else params["head"]
+        logits = x @ head
+        spec = (P(L._DP_ALL, None, None) if cfg.sharding_profile == "fsdp"
+                else P(("pod", "data"), None, "model"))
+        return maybe_shard(logits, spec)
 
 
 def forward(cfg: ArchConfig, params: Params, tokens, *, lens=None,

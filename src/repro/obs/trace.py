@@ -15,6 +15,13 @@ load and an ``is None`` test when tracing is off::
 Recorded traces export to Chrome ``trace_event`` JSON — load the file at
 ``ui.perfetto.dev`` or ``chrome://tracing`` (see
 ``docs/observability.md``).
+
+``Tracer(profiler=True)`` also mirrors every duration span into the JAX
+profiler as a ``jax.profiler.TraceAnnotation`` of the same name, so a
+profile taken meanwhile (``jax.profiler.trace``) holds the program's
+spans on its host plane, on the clock of the device operations.  The
+buffer keeps its own clock; ``ts + tracer.origin`` is a buffer event's
+reading of that clock.
 """
 from __future__ import annotations
 
@@ -29,11 +36,13 @@ from .clock import CLOCK, Clock
 class _OpenSpan:
     """Handle returned by :meth:`Tracer.begin`; call :meth:`end` once."""
 
-    __slots__ = ("tracer", "idx")
+    __slots__ = ("tracer", "idx", "annotation")
 
-    def __init__(self, tracer: "Tracer", idx: Optional[int]):
+    def __init__(self, tracer: "Tracer", idx: Optional[int],
+                 annotation: Any = None):
         self.tracer = tracer
         self.idx = idx
+        self.annotation = annotation
 
     def end(self, **args: Any) -> None:
         self.tracer.end(self, **args)
@@ -48,17 +57,28 @@ class Tracer:
     Chrome ``trace_event`` schema.  The buffer is capped at
     ``max_events``; overflow increments :attr:`dropped` instead of
     growing without bound.
+
+    With ``profiler=True`` every duration span also opens and closes a
+    ``jax.profiler.TraceAnnotation`` of its name (instants, async and
+    counter events stay in the buffer only).  :attr:`origin` is the
+    clock's reading at construction: an event's ``ts`` plus ``origin``
+    is the clock's own reading at the event.
     """
 
     def __init__(self, *, max_events: int = 200_000,
-                 clock: Optional[Clock] = None):
+                 clock: Optional[Clock] = None, profiler: bool = False):
         self.clock = clock or CLOCK
         self.max_events = max_events
         self.events: List[Dict[str, Any]] = []
         self.dropped = 0
         self._lock = threading.Lock()
         self._stacks: Dict[int, List[Optional[int]]] = {}
-        self._t0 = self.clock()
+        self._annotate = None
+        if profiler:
+            import jax
+
+            self._annotate = jax.profiler.TraceAnnotation
+        self.origin = self.clock()
 
     # ---- recording --------------------------------------------------
     def _stack(self) -> List[Optional[int]]:
@@ -82,21 +102,27 @@ class Tracer:
         st = self._stack()
         parent = next((i for i in reversed(st) if i is not None), -1)
         rec = {"name": name, "cat": cat, "ph": "X",
-               "ts": self.clock() - self._t0, "dur": None,
+               "ts": self.clock() - self.origin, "dur": None,
                "tid": threading.get_ident(), "args": dict(args),
                "parent": parent, "depth": len(st)}
         idx = self._append(rec)
         st.append(idx)
-        return _OpenSpan(self, idx)
+        ann = None
+        if self._annotate is not None:
+            ann = self._annotate(name)
+            ann.__enter__()
+        return _OpenSpan(self, idx, ann)
 
     def end(self, span: _OpenSpan, **args: Any) -> None:
+        if span.annotation is not None:
+            span.annotation.__exit__(None, None, None)
         st = self._stack()
         if st:
             st.pop()
         if span.idx is None:
             return
         rec = self.events[span.idx]
-        rec["dur"] = self.clock() - self._t0 - rec["ts"]
+        rec["dur"] = self.clock() - self.origin - rec["ts"]
         if args:
             rec["args"].update(args)
 
@@ -114,7 +140,7 @@ class Tracer:
         st = self._stack()
         parent = next((i for i in reversed(st) if i is not None), -1)
         self._append({"name": name, "cat": cat, "ph": "i",
-                      "ts": self.clock() - self._t0,
+                      "ts": self.clock() - self.origin,
                       "tid": threading.get_ident(), "args": dict(args),
                       "parent": parent, "depth": len(st)})
 
@@ -123,14 +149,14 @@ class Tracer:
         """Open an async span (``ph: "b"``) keyed by ``id`` — used for
         per-request serve lifecycles that outlive any one call stack."""
         self._append({"name": name, "cat": cat, "ph": "b",
-                      "ts": self.clock() - self._t0, "id": str(id),
+                      "ts": self.clock() - self.origin, "id": str(id),
                       "tid": threading.get_ident(), "args": dict(args),
                       "parent": -1, "depth": 0})
 
     def async_end(self, name: str, id: Any, cat: str = "serve",
                   **args: Any) -> None:
         self._append({"name": name, "cat": cat, "ph": "e",
-                      "ts": self.clock() - self._t0, "id": str(id),
+                      "ts": self.clock() - self.origin, "id": str(id),
                       "tid": threading.get_ident(), "args": dict(args),
                       "parent": -1, "depth": 0})
 
@@ -138,7 +164,7 @@ class Tracer:
                 cat: str = "disc") -> None:
         """A counter sample (``ph: "C"``) — renders as a track."""
         self._append({"name": name, "cat": cat, "ph": "C",
-                      "ts": self.clock() - self._t0,
+                      "ts": self.clock() - self.origin,
                       "tid": threading.get_ident(),
                       "args": {k: float(v) for k, v in values.items()},
                       "parent": -1, "depth": 0})

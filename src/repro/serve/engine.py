@@ -131,8 +131,6 @@ STATS_KEYS: Dict[str, str] = {
     "tokens_generated": "tokens produced (incl. each prompt's first token "
                         "at prefill completion)",
     "tokens_per_sec": "tokens_generated / busy seconds inside step()",
-    "max_decode_gap_s": "longest wall-clock gap between decode launches "
-                        "while decode work was pending (decode stall)",
     "requests_completed": "requests retired into done",
     "rejected_requests": "requests refused at submit(): prompt longer than "
                          "max_seq, or a worst-case footprint larger than "
@@ -333,7 +331,7 @@ class ServeEngine:
         self._recomputes: Dict[int, int] = {}   # rid -> preempt count
         self._deadlines: Dict[int, float] = {}  # rid -> absolute deadline
         self._clock = time.monotonic            # injectable (tests/docs)
-        self._wall = _WALL      # perf timing (busy_s, decode gaps) only
+        self._wall = _WALL      # perf timing (busy_s) only
         self._retry = scfg.launch_retry or DEFAULT_RETRY
         self._kdem0 = len(KERNEL_DEMOTIONS)
         self._replica_alive = [True] * scfg.replicas
@@ -352,7 +350,6 @@ class ServeEngine:
         self._decode_credit = 0
         self._bucket_pairs: Set[Tuple[int, int]] = set()
         self._busy_s = 0.0
-        self._last_decode_t: Optional[float] = None
         self._aseq = 0                  # admission sequence counter
         self._carry: Dict[int, List[int]] = {}  # rid -> generated-so-far
         self._rep_counters = [
@@ -625,6 +622,10 @@ class ServeEngine:
         self.stats["rejected_requests"] += len(dropped)
         self.rejected.extend(dropped)
         self.queue.extend(accepted)
+        if obs_trace.ACTIVE is not None:
+            for r in accepted:
+                obs_trace.ACTIVE.async_begin("request.queued", id=r.rid,
+                                             prompt_len=len(r.tokens))
 
     def _replica_of(self, slot: int) -> int:
         return slot // self.scfg.max_batch
@@ -659,6 +660,8 @@ class ServeEngine:
         # never by Request.__eq__ (numpy token arrays make dataclass
         # equality ambiguous-truth-value prone)
         taken: Set[int] = set()
+        sp = (obs_trace.ACTIVE.begin("serve.admit", cat="serve")
+              if obs_trace.ACTIVE is not None else None)
         for req in self._admit_order(self.queue):
             if len(taken) >= n_free:
                 break
@@ -686,11 +689,14 @@ class ServeEngine:
             self.lens[i] = 0
             self._rep_counters[rep]["admitted"] += 1
             if obs_trace.ACTIVE is not None:
+                obs_trace.ACTIVE.async_end("request.queued", id=req.rid)
                 obs_trace.ACTIVE.async_begin(
                     "request", id=req.rid, replica=rep, slot=i,
                     prompt_len=int(toks.shape[0]),
                     resumed=bool(carried))
         self.queue = [r for r in self.queue if r.rid not in taken]
+        if sp is not None:
+            sp.end(rids=sorted(taken))
 
     # -------------------------------------------------------- fault plane --
     def _forget(self, rid: int) -> None:
@@ -803,6 +809,9 @@ class ServeEngine:
         for r in still:
             self.stats["deadline_expirations"] += 1
             obs_metrics.record_event("deadline.expire", rid=r.rid)
+            if obs_trace.ACTIVE is not None:
+                obs_trace.ACTIVE.async_end("request.queued", id=r.rid,
+                                           failed=True)
             self._fail_request(r.rid, "DeadlineExceeded: deadline_s "
                                       "passed before completion")
         self.queue = [r for r in self.queue if r.rid not in expired]
@@ -839,6 +848,12 @@ class ServeEngine:
                 self.stats["kv_evictions"] += freed
         obs_metrics.record_event("preempt", rid=slot.rid, slot=i,
                                  drain=drain)
+        if obs_trace.ACTIVE is not None:
+            obs_trace.ACTIVE.async_end("request", id=slot.rid,
+                                       preempted=True)
+            obs_trace.ACTIVE.async_begin("request.queued", id=slot.rid,
+                                         prompt_len=int(slot.plen),
+                                         requeued=True)
         toks = slot.tokens
         if slot.generated:
             toks = np.concatenate(
@@ -909,6 +924,14 @@ class ServeEngine:
         idx = np.asarray([i for i, _ in members])
         nb = len(members)
         smax = max(cl for _, cl in members)
+        bucket = (min(self.scfg.batch_policy.bucket("B", nb), self.n_slots),
+                  min(self.scfg.prefill_policy.bucket("S", smax),
+                      self.scfg.max_seq))
+        tr = obs_trace.ACTIVE
+        group = (tr.begin("serve.prefill_group", cat="serve",
+                          rids=[self.slots[i].rid for i, _ in members],
+                          lens=[cl for _, cl in members], B=bucket[0],
+                          S=bucket[1]) if tr is not None else None)
         tokens = np.zeros((nb, smax), np.int32)
         lens = np.zeros((nb,), np.int32)
         offsets = np.zeros((nb,), np.int32)
@@ -925,8 +948,12 @@ class ServeEngine:
                     "prefill", self._prefill_fn, self.params,
                     self.pool.tree, tview, tokens, lens, offsets)
             else:
+                sp = (tr.begin("serve.rows.gather", cat="serve")
+                      if tr is not None else None)
                 rows = jax.tree.map(
                     lambda c: c[:, idx] if c.ndim > 1 else c, self.cache)
+                if sp is not None:
+                    sp.end()
                 logits, new_rows = self._launch(
                     "prefill", self._prefill_fn, self.params, rows, tokens,
                     lens, offsets)
@@ -935,10 +962,14 @@ class ServeEngine:
             # decode-state requests are untouched
             for i, _ in members:
                 self._fail_slot(i, f"LaunchError(prefill): {e}")
+            if group is not None:
+                group.end(error=True)
             return
         if self.paged:
             self.pool.tree = new_pool
         else:
+            sp = (tr.begin("serve.rows.scatter", cat="serve")
+                  if tr is not None else None)
             self.cache = jax.tree.map(
                 lambda full, row: full.at[:, idx].set(
                     row[:, :nb].astype(full.dtype))
@@ -949,12 +980,15 @@ class ServeEngine:
                 # the cache back to its planned layout so the decode
                 # artifact's jit entries never retrace on a sharding flip
                 self.cache = self._put_cache(self.cache)
+            if sp is not None:
+                sp.end()
+        sp = (tr.begin("serve.first_tokens", cat="serve")
+              if tr is not None else None)
         last = np.asarray(logits[:nb])
+        if sp is not None:
+            sp.end()
 
-        self._bucket_pairs.add((
-            min(self.scfg.batch_policy.bucket("B", nb), self.n_slots),
-            min(self.scfg.prefill_policy.bucket("S", smax),
-                self.scfg.max_seq)))
+        self._bucket_pairs.add(bucket)
         self.stats["prefill_calls"] += 1
         if nb > 1:
             self.stats["batched_prefills"] += 1
@@ -966,6 +1000,9 @@ class ServeEngine:
             if s.pos >= s.plen:
                 s.state = "decode"
                 s.generated.append(int(np.argmax(last[r])))
+                if tr is not None and len(s.generated) == 1:
+                    tr.instant("request.first_token", cat="serve",
+                               rid=s.rid)
                 if s.resumed:
                     # a resumed prompt replays previously generated
                     # tokens: its completion token is a fresh one and
@@ -981,6 +1018,8 @@ class ServeEngine:
                 chunked = True
         if chunked:
             self.stats["prefill_chunks"] += 1
+        if group is not None:
+            group.end()
 
     def _decode(self) -> None:
         """One decode launch over ALL replicas' rows — the tokens-per-
@@ -990,18 +1029,27 @@ class ServeEngine:
         speculative verify instead."""
         active_idx = [i for i, s in enumerate(self.slots)
                       if s is not None and s.state == "decode"]
-        if self._proposer is not None:
-            self._decode_speculative(active_idx)
-        else:
-            self._decode_plain(active_idx)
+        sp = (obs_trace.ACTIVE.begin(
+            "serve.decode_step", cat="serve",
+            rids=[self.slots[i].rid for i in active_idx])
+            if obs_trace.ACTIVE is not None else None)
+        try:
+            if self._proposer is not None:
+                self._decode_speculative(active_idx)
+            else:
+                self._decode_plain(active_idx)
+        finally:
+            if sp is not None:
+                sp.end()
 
-    def _mark_decode_launch(self) -> None:
-        now = self._wall()
-        if self._last_decode_t is not None:
-            self.stats["max_decode_gap_s"] = max(
-                self.stats["max_decode_gap_s"], now - self._last_decode_t)
-        self._last_decode_t = now
-        self.stats["decode_steps"] += 1
+    def _wait_tokens(self, x) -> np.ndarray:
+        """The sampled tokens on the host: the wait for the step."""
+        sp = (obs_trace.ACTIVE.begin("serve.tokens", cat="serve")
+              if obs_trace.ACTIVE is not None else None)
+        out = np.asarray(x, np.int32)
+        if sp is not None:
+            sp.end()
+        return out
 
     def _decode_plain(self, active_idx: List[int]) -> None:
         if self.paged:
@@ -1044,8 +1092,8 @@ class ServeEngine:
             for i in active_idx:
                 self._fail_slot(i, f"LaunchError(decode): {e}")
             return
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
-        self._mark_decode_launch()
+        nxt = self._wait_tokens(jnp.argmax(logits[:, 0], axis=-1))
+        self.stats["decode_steps"] += 1
         for i in active_idx:
             slot = self.slots[i]
             self.lens[i] += 1
@@ -1114,8 +1162,8 @@ class ServeEngine:
             for i in live:
                 self._fail_slot(i, f"LaunchError(verify): {e}")
             return
-        ids = np.asarray(ids)
-        self._mark_decode_launch()
+        ids = self._wait_tokens(ids)
+        self.stats["decode_steps"] += 1
         for i in live:
             s = self.slots[i]
             dr = drafts[i]
@@ -1165,25 +1213,28 @@ class ServeEngine:
         decode step — the ``prefill_interleave`` budget decides which when
         both kinds of work are pending."""
         t0 = self._wall()
-        if self.monitor is not None:
-            self._check_replicas()
-        self._check_deadlines()
-        self._admit()
-        has_p = any(s is not None and s.state == "prefill"
-                    for s in self.slots)
-        has_d = any(s is not None and s.state == "decode"
-                    for s in self.slots)
-        if has_p and (not has_d or self._decode_credit <= 0):
-            self._prefill_group()
-            self._decode_credit = max(self.scfg.prefill_interleave, 0)
-        elif has_d:
-            self._decode()
-            self._decode_credit -= 1
-        if not any(s is not None and s.state == "decode"
-                   for s in self.slots):
-            self._last_decode_t = None  # decode idle: gaps don't count
-        self._busy_s += self._wall() - t0
-        self._refresh_stats()
+        sp = (obs_trace.ACTIVE.begin("serve.step", cat="serve")
+              if obs_trace.ACTIVE is not None else None)
+        try:
+            if self.monitor is not None:
+                self._check_replicas()
+            self._check_deadlines()
+            self._admit()
+            has_p = any(s is not None and s.state == "prefill"
+                        for s in self.slots)
+            has_d = any(s is not None and s.state == "decode"
+                        for s in self.slots)
+            if has_p and (not has_d or self._decode_credit <= 0):
+                self._prefill_group()
+                self._decode_credit = max(self.scfg.prefill_interleave, 0)
+            elif has_d:
+                self._decode()
+                self._decode_credit -= 1
+            self._busy_s += self._wall() - t0
+            self._refresh_stats()
+        finally:
+            if sp is not None:
+                sp.end()
 
     def run_until_done(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
         steps = 0
@@ -1262,8 +1313,8 @@ class ServeEngine:
         ``per_replica`` is a fresh list of per-replica counter dicts —
         never the scalar 0 a uniform ``= 0`` sweep would leave behind."""
         z: Dict[str, Any] = {k: 0 for k in STATS_KEYS}
-        for k in ("tokens_per_sec", "max_decode_gap_s",
-                  "kv_pool_occupancy", "kv_peak_occupancy"):
+        for k in ("tokens_per_sec", "kv_pool_occupancy",
+                  "kv_peak_occupancy"):
             z[k] = 0.0
         z["per_replica"] = [
             {"admitted": 0, "tokens_generated": 0,
@@ -1283,7 +1334,6 @@ class ServeEngine:
             {"admitted": 0, "tokens_generated": 0, "requests_completed": 0}
             for _ in range(self.scfg.replicas)]
         self._busy_s = 0.0
-        self._last_decode_t = None
         self._kdem0 = len(KERNEL_DEMOTIONS)   # demotion delta restarts
         self._refresh_stats()
 

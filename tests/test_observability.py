@@ -17,9 +17,19 @@ Contracts under test:
   key to its documented type (the old uniform ``= 0`` clobbered
   ``per_replica``'s list-of-dicts to an int);
 * **one clock** — heartbeats and the obs clock are injectable and
-  deterministic under a fixed source.
+  deterministic under a fixed source;
+* **the profiler's clock** — ``Tracer(profiler=True)`` puts the
+  engine's spans on the host plane of a JAX profile, nested as in the
+  buffer;
+* **compile spans time the compile** — on the ``jit`` pipeline the
+  ``compile.bucket`` / ``compile.exact`` span is the entry's first call;
+* **named model scopes** change HLO metadata only.
 """
+import contextlib
+import glob
 import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -404,12 +414,11 @@ class TestResetStats:
         for rep in eng.stats["per_replica"]:
             assert rep == {"admitted": 0, "tokens_generated": 0,
                            "requests_completed": 0, "occupied_slots": 0}
-        for k in ("tokens_per_sec", "max_decode_gap_s",
-                  "kv_pool_occupancy", "kv_peak_occupancy"):
+        for k in ("tokens_per_sec", "kv_pool_occupancy",
+                  "kv_peak_occupancy"):
             assert isinstance(eng.stats[k], float)
         ints = set(STATS_KEYS) - {"per_replica", "tokens_per_sec",
-                                  "max_decode_gap_s", "kv_pool_occupancy",
-                                  "kv_peak_occupancy"}
+                                  "kv_pool_occupancy", "kv_peak_occupancy"}
         assert all(eng.stats[k] == 0 and isinstance(eng.stats[k], int)
                    for k in ints)
 
@@ -511,3 +520,244 @@ class TestClocks:
             sp.end()
         ev = tr.events[0]
         assert ev["ts"] == 0.0 and ev["dur"] == 0.25
+
+
+# ------------------------------------------------------ profiler mirror --
+
+def _intervals(trace_dir, names):
+    """Host-plane events of the newest profile under ``trace_dir``, by
+    name: ``{name: [(start_ns, end_ns), ...]}``."""
+    path = max(glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    data = jax.profiler.ProfileData.from_file(path)
+    out = {n: [] for n in names}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in out:
+                    out[ev.name].append((ev.start_ns,
+                                         ev.start_ns + ev.duration_ns))
+    return out
+
+
+def _inside(child, parents):
+    return any(a <= child[0] and child[1] <= b for a, b in parents)
+
+
+def _parent_name(tr, ev):
+    return tr.events[ev["parent"]]["name"] if ev["parent"] >= 0 else None
+
+
+class TestProfilerMirror:
+    def test_engine_spans_on_the_host_plane(self, tiny, tmp_path):
+        cfg, model, params = tiny
+        eng = _engine(model, params)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        with obs_trace.tracing(obs_trace.Tracer(profiler=True)) as tr:
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                eng.submit(_requests(cfg.vocab, [5, 9, 12]))
+                eng.run_until_done(max_steps=200)
+            finally:
+                jax.profiler.stop_trace()
+        names = ("serve.step", "serve.prefill_group", "serve.decode_step",
+                 "serve.prefill", "serve.tokens")
+        host = _intervals(tmp_path, names)
+        for n in names:
+            assert len(host[n]) == len(tr.spans(n)) > 0, n
+        assert all(_inside(g, host["serve.step"])
+                   for g in host["serve.prefill_group"])
+        assert all(_inside(d, host["serve.step"])
+                   for d in host["serve.decode_step"])
+        assert all(_inside(p, host["serve.prefill_group"])
+                   for p in host["serve.prefill"])
+        assert all(_inside(t, host["serve.decode_step"])
+                   for t in host["serve.tokens"])
+
+    def test_buffer_nesting_and_origin(self, tiny):
+        cfg, model, params = tiny
+        eng = _engine(model, params)
+        t0 = CLOCK()
+        with obs_trace.tracing() as tr:
+            eng.submit(_requests(cfg.vocab, [5, 9, 12]))
+            eng.run_until_done(max_steps=200)
+        t1 = CLOCK()
+        want = {"serve.admit": "serve.step",
+                "serve.prefill_group": "serve.step",
+                "serve.decode_step": "serve.step",
+                "serve.rows.gather": "serve.prefill_group",
+                "serve.rows.scatter": "serve.prefill_group",
+                "serve.first_tokens": "serve.prefill_group",
+                "serve.prefill": "serve.prefill_group",
+                "serve.decode": "serve.decode_step",
+                "serve.tokens": "serve.decode_step"}
+        for child, parent in want.items():
+            spans = tr.spans(child)
+            assert spans, child
+            assert {_parent_name(tr, e) for e in spans} == {parent}, child
+        assert all(_parent_name(tr, e) is None
+                   for e in tr.spans("serve.step"))
+        steps = tr.spans("serve.step")
+        assert t0 <= steps[0]["ts"] + tr.origin
+        assert steps[-1]["ts"] + steps[-1]["dur"] + tr.origin <= t1
+        group = tr.spans("serve.prefill_group")[0]
+        assert group["args"]["rids"] == [0, 1]
+        assert group["args"]["lens"] == [5, 9]
+        assert (group["args"]["B"], group["args"]["S"]) == (2, 16)
+        admits = [e["args"]["rids"] for e in tr.spans("serve.admit")]
+        assert sorted(r for a in admits for r in a) == [0, 1, 2]
+        dec = tr.spans("serve.decode_step")
+        assert len(dec) == eng.stats["decode_steps"]
+        assert set(dec[0]["args"]["rids"]) <= {0, 1}
+
+
+class TestRequestLifecycle:
+    def test_queued_from_submit_to_admission(self, tiny):
+        cfg, model, params = tiny
+        eng = _engine(model, params)            # two slots, three requests
+        with obs_trace.tracing() as tr:
+            eng.submit(_requests(cfg.vocab, [5, 9, 12]))
+            n_submit = len(tr.events)
+            eng.run_until_done(max_steps=200)
+        queued = tr.find("request.queued")
+        opens = [e for e in queued if e["ph"] == "b"]
+        assert [e["id"] for e in opens] == ["0", "1", "2"]
+        assert all(tr.events.index(e) < n_submit for e in opens)
+        assert opens[2]["args"]["prompt_len"] == 12
+        for rid in ("0", "1", "2"):
+            ev = [e for e in tr.events if e.get("id") == rid]
+            # queued opens, queued closes, then the request span opens
+            assert [(e["name"], e["ph"]) for e in ev][:3] == [
+                ("request.queued", "b"), ("request.queued", "e"),
+                ("request", "b")]
+            close = ev[1]
+            admit = next(a for a in tr.spans("serve.admit")
+                         if int(rid) in a["args"]["rids"])
+            assert admit["ts"] <= close["ts"] <= admit["ts"] + admit["dur"]
+        # the third waits for a slot: admitted after a request retired
+        retired = min(e["ts"] for e in tr.find("request") if e["ph"] == "e")
+        assert ev[1]["ts"] >= retired
+
+    @pytest.mark.parametrize("chunk", [None, 4])
+    def test_first_token_once_per_request(self, tiny, chunk):
+        cfg, model, params = tiny
+        eng = _engine(model, params, prefill_chunk=chunk)
+        with obs_trace.tracing() as tr:
+            eng.submit(_requests(cfg.vocab, [5, 9, 12]))
+            eng.run_until_done(max_steps=400)
+        firsts = tr.find("request.first_token")
+        assert sorted(e["args"]["rid"] for e in firsts) == [0, 1, 2]
+        assert all(e["ph"] == "i" and _parent_name(tr, e) ==
+                   "serve.prefill_group" for e in firsts)
+        # the token is on the host: after its group's wait for logits
+        for e in firsts:
+            (wait,) = [w for w in tr.spans("serve.first_tokens")
+                       if w["parent"] == e["parent"]]
+            assert e["ts"] >= wait["ts"] + wait["dur"]
+
+    def test_queued_request_failing_closes_queued_span(self, tiny):
+        cfg, model, params = tiny
+        eng = _engine(model, params, max_batch=1)
+        eng._clock = lambda: 100.0
+        with obs_trace.tracing() as tr:
+            eng.submit([Request(rid=5, tokens=np.arange(4, dtype=np.int32),
+                                max_new_tokens=2, deadline_s=-200.0)])
+            eng.step()
+        q = tr.find("request.queued")
+        assert [e["ph"] for e in q] == ["b", "e"]
+        assert q[1]["args"]["failed"] is True
+
+
+# ------------------------------------------------------ compile spans --
+
+class TestCompileSpans:
+    def test_jit_compile_span_is_the_first_entry_call(self):
+        f = _artifact("jit")
+        with obs_trace.tracing() as tr:
+            f(np.ones((3, 4), np.float32))      # miss: traces + compiles
+            f(np.ones((3, 4), np.float32))      # hit
+        comp = tr.spans("compile.bucket")
+        assert len(comp) == 1
+        c = comp[0]
+        miss = tr.spans("dispatch")[0]
+        assert c["parent"] == tr.events.index(miss)
+        # JAX's own trace and backend compile ran inside the span
+        assert c["args"]["trace_s"] > 0.0
+        assert c["args"]["backend_compile_s"] > 0.0
+        assert c["dur"] >= (c["args"]["trace_s"] + c["args"]["lower_s"]
+                            + c["args"]["backend_compile_s"])
+        assert c["args"]["persistent_cache_hit"] in (True, False)
+        # the span is the entry call, inside the dispatch's timed call
+        assert c["dur"] <= miss["args"]["entry_seconds"]
+        st = f.cache.stats
+        assert st.compile_seconds == pytest.approx(c["dur"], rel=0.05,
+                                                   abs=1e-3)
+        # a later call to the bucket compiles nothing
+        with obs_trace.tracing() as tr2:
+            f(np.ones((5, 4), np.float32))
+        assert not tr2.spans("compile.bucket")
+        assert f.cache.stats.compile_seconds == st.compile_seconds
+
+    def test_jit_exact_compile_span_is_the_first_call(self):
+        f = disc.compile(lambda x: x * 2.0, [ArgSpec(("S", 4), jnp.float32)],
+                         options=disc.CompileOptions(
+                             pipeline="jit", escalation_threshold=2))
+        with obs_trace.tracing() as tr:
+            for _ in range(3):
+                f(np.ones((5, 4), np.float32))
+        exact = tr.spans("compile.exact")
+        assert len(exact) == 1
+        assert exact[0]["args"]["backend_compile_s"] > 0.0
+        assert exact[0]["dur"] >= exact[0]["args"]["backend_compile_s"]
+
+    def test_dhlo_compile_span_times_the_build(self):
+        f = _artifact("dhlo")
+        with obs_trace.tracing() as tr:
+            f(np.ones((3, 4), np.float32))
+        (c,) = tr.spans("compile.bucket")
+        assert c["args"]["backend_compile_s"] > 0.0
+        assert c["dur"] >= c["args"]["backend_compile_s"]
+        assert f.cache.stats.compile_seconds == pytest.approx(
+            c["dur"], rel=0.05, abs=1e-3)
+
+
+# -------------------------------------------------------- model scopes --
+
+_METADATA = re.compile(r",? metadata=\{[^}]*\}")
+
+
+def _program(hlo):
+    """The computations of a compiled HLO text without their metadata
+    (the source-location tables between the header and the first
+    computation go too)."""
+    return [_METADATA.sub("", ln) for ln in hlo.splitlines()
+            if ln.startswith(("%", "ENTRY", "}", "  "))]
+
+
+def _prefill_hlo(model, params):
+    cache = model.init_cache(2, 32)
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    lens = jnp.asarray([5, 8], jnp.int32)
+    offsets = jnp.zeros((2,), jnp.int32)
+    return jax.jit(lambda *a: model.prefill(*a)).lower(
+        params, cache, tokens, lens, offsets).compile().as_text()
+
+
+class TestModelScopes:
+    def test_scopes_are_metadata_only(self, tiny, monkeypatch):
+        cfg, model, params = tiny
+        scoped = _prefill_hlo(model, params)
+        ops = set(re.findall(r'op_name="([^"]*)"', scoped))
+        for scope in ("embed", "norm", "attn/qkv", "attn/cache_write",
+                      "attn/core", "attn/out", "mlp", "head"):
+            assert any(f"/{scope}/" in o for o in ops), scope
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        plain = _prefill_hlo(model, params)
+        assert "attn/cache_write" not in plain
+        assert _program(scoped) == _program(plain)
+        assert len(_program(plain)) > 100

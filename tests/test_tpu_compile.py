@@ -130,3 +130,9 @@ def test_minitron_decode_step_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert params["head"].shape == (3072, 256000)
     assert total < HBM_BYTES, total
+    # the model's named scopes survive the TPU compiler into the
+    # op_name metadata a device profile reads
+    text = c.as_text()
+    for scope in ("attn/qkv", "attn/cache_write", "attn/core", "mlp",
+                  "head"):
+        assert f"/{scope}/" in text, scope
