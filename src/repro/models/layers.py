@@ -232,6 +232,38 @@ def _sdpa(q, k, v, *, causal: bool, lens: Optional[jax.Array],
     return o.reshape(b, h, sq, hd).astype(q.dtype)
 
 
+def write_chunk(cache: jax.Array, chunk: jax.Array, offsets: jax.Array,
+                lens: jax.Array) -> jax.Array:
+    """Write row r's first ``lens[r]`` chunk positions at cache positions
+    ``[offsets[r], offsets[r] + lens[r])``; every other cache element is
+    left bit-identical.
+
+    ``cache`` (B, H, Lc, hd), ``chunk`` (B, H, S, hd), ``offsets`` >= 0.
+    Each row reads and rewrites one window of ``W = min(S, Lc)``
+    positions, so the work scales with B x S: the window starts at
+    ``offset`` clipped to ``[0, Lc - W]`` (a plain dynamic update slice
+    would clamp a chunk that runs past ``Lc`` onto the wrong positions),
+    and the chunk, left-padded with W zeros, is sliced at the resulting
+    shift so that window position j holds chunk position ``j - shift``.
+    """
+    b, _, lc, _ = cache.shape
+    w = min(chunk.shape[2], lc)
+    padded = jnp.pad(chunk.astype(cache.dtype),
+                     ((0, 0), (0, 0), (w, 0), (0, 0)))
+    start = jnp.clip(offsets, 0, lc - w)
+    shift = offsets - start
+    j = jnp.arange(w)[None, :] - shift[:, None]                 # (B, W)
+    written = (j >= 0) & (j < lens[:, None])
+    for r in range(b):
+        window = jax.lax.dynamic_slice_in_dim(cache[r], start[r], w, axis=1)
+        src = jax.lax.dynamic_slice_in_dim(padded[r], w - shift[r], w,
+                                           axis=1)
+        row = jnp.where(written[r][None, :, None], src, window)
+        cache = jax.lax.dynamic_update_slice(cache, row[None],
+                                             (r, 0, start[r], 0))
+    return cache
+
+
 def attn_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
                positions: jax.Array, lens: Optional[jax.Array] = None,
                cache: Optional[Params] = None, causal: bool = True,
@@ -242,9 +274,11 @@ def attn_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
     ``cache`` + ``offsets`` switches to *batched prefill* mode instead
     (serve path): x is a (B, S, D) chunk whose row r holds ``lens[r]``
     true tokens destined for absolute cache positions
-    ``[offsets[r], offsets[r] + lens[r])``; the chunk's K/V are scattered
-    into the cache in one pass and queries attend causally against the
-    whole cache at absolute positions.
+    ``[offsets[r], offsets[r] + lens[r])``; the chunk's K/V reach the
+    cache by a per-row slice write (:func:`write_chunk`, work in B x S,
+    not B x max_seq) that leaves every other position, padded chunk
+    positions included, untouched; queries then attend causally against
+    the whole cache at absolute positions.
 
     ``kv_source`` enables cross-attention (whisper decoder).
 
@@ -269,21 +303,13 @@ def attn_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
         v = v.transpose(0, 2, 1, 3)
     new_cache = None
     if cache is not None and offsets is not None:
-        # batched prefill: scatter the chunk's K/V to absolute positions
-        # [offset, offset+len) per row — padded chunk positions are never
-        # written — then attend causally against the whole cache
+        # batched prefill: a per-row slice write puts the chunk's K/V at
+        # absolute positions [offset, offset+len) — padded chunk positions
+        # are never written — then queries attend causally against the
+        # whole cache
         with jax.named_scope("attn/cache_write"):
-            kc, vc = cache["k"], cache["v"]
-            lc = kc.shape[2]
-            j = jnp.arange(lc)[None, :] - offsets[:, None]      # (B, Lc)
-            written = (j >= 0) & (j < lens[:, None])
-            jc = jnp.clip(j, 0, s - 1)
-            idx = jnp.broadcast_to(jc[:, None, :, None], (b, hkv, lc, hd))
-            wmask = written[:, None, :, None]
-            kc = jnp.where(wmask, jnp.take_along_axis(k, idx, axis=2)
-                           .astype(kc.dtype), kc)
-            vc = jnp.where(wmask, jnp.take_along_axis(v, idx, axis=2)
-                           .astype(vc.dtype), vc)
+            kc = write_chunk(cache["k"], k, offsets, lens)
+            vc = write_chunk(cache["v"], v, offsets, lens)
             new_cache = {"k": kc, "v": vc}
         with jax.named_scope("attn/core"):
             o = _sdpa(q, kc.astype(q.dtype), vc.astype(q.dtype), causal=True,

@@ -2,11 +2,14 @@
 
 Covers the continuous-batching serve path end to end: single-pass batched
 prefill parity against the sequential replay baseline (model- and
-engine-level, across ≥2 (batch, seq) buckets), chunked prefill vs
-unchunked, admission-policy ordering, O(#(B, S) buckets) compile counts
-under varying batch composition, §4.4 escalation on the batched artifact,
-and the ``TreeSpec`` pytree padding it rides on.
+engine-level, across ≥2 (batch, seq) buckets), the batched-prefill cache
+write against its old gather formula, chunked prefill vs unchunked,
+admission-policy ordering, O(#(B, S) buckets) compile counts under
+varying batch composition, §4.4 escalation on the batched artifact, and
+the ``TreeSpec`` pytree padding it rides on.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +18,7 @@ import pytest
 import disc
 from repro.configs import get_config
 from repro.data.pipeline import Request
+from repro.models import layers as L
 from repro.models.registry import get_model, replay_prefill
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.policies import (ADMISSION_POLICIES, get_admission_policy,
@@ -125,6 +129,97 @@ class TestPrefillParity:
             if chunk:
                 assert eng.stats["prefill_chunks"] > 0
         assert base == chunked
+
+
+# ------------------------------------------------------------ cache write --
+
+def _gather_write(kc, k, offsets, lens):
+    """The element-wise formula the slice write replaced: an index tensor
+    over the whole cache row and a ``take_along_axis`` gather, kept as the
+    reference."""
+    b, hkv, lc, hd = kc.shape
+    j = jnp.arange(lc)[None, :] - offsets[:, None]
+    written = (j >= 0) & (j < lens[:, None])
+    idx = jnp.broadcast_to(jnp.clip(j, 0, k.shape[2] - 1)[:, None, :, None],
+                           (b, hkv, lc, hd))
+    return jnp.where(written[:, None, :, None],
+                     jnp.take_along_axis(k, idx, axis=2).astype(kc.dtype), kc)
+
+
+def _write_case(kind, b, rng, s=8, lc=24):
+    """(offsets, lens, cache length) for one batched-prefill write."""
+    lens = rng.randint(1, s + 1, size=b)
+    if kind == "fresh":
+        offsets = np.zeros(b, int)
+    elif kind == "offset":
+        offsets = rng.randint(1, lc - s + 1, size=b)
+    elif kind == "overrun":          # offset + S runs past max_seq
+        offsets = rng.randint(lc - s + 1, lc, size=b)
+        lens[0] = s
+    elif kind == "empty_rows":       # bucket-padded rows: lens 0
+        offsets = rng.randint(0, lc, size=b)
+        lens[::2] = 0
+    else:                            # chunk wider than the cache
+        lc = s - 3
+        offsets = rng.randint(0, lc, size=b)
+    return (jnp.asarray(offsets, jnp.int32), jnp.asarray(lens, jnp.int32),
+            lc)
+
+
+def _cache_write_gathers(hlo):
+    return [ln for ln in hlo.splitlines()
+            if re.search(r"\bgather\(", ln) and "attn/cache_write" in ln]
+
+
+class TestCacheWrite:
+    @pytest.mark.parametrize("kind", ["fresh", "offset", "overrun",
+                                      "empty_rows", "wide_chunk"])
+    @pytest.mark.parametrize("b", [1, 2, 3, 4])
+    def test_slice_write_matches_gather(self, b, kind):
+        """Bit for bit the gather formula; only [offset, offset+len)
+        changes in each row."""
+        rng = np.random.RandomState(100 * b + len(kind))
+        s, hkv, hd = 8, 2, 4
+        offsets, lens, lc = _write_case(kind, b, rng, s=s)
+        kc = jnp.asarray(rng.randn(b, hkv, lc, hd), jnp.bfloat16)
+        k = jnp.asarray(rng.randn(b, hkv, s, hd), jnp.float32)
+        got = np.asarray(jax.jit(L.write_chunk)(kc, k, offsets, lens))
+        want = np.asarray(jax.jit(_gather_write)(kc, k, offsets, lens))
+        np.testing.assert_array_equal(got.view(np.uint16),
+                                      want.view(np.uint16))
+        old, new_ = np.asarray(kc), np.asarray(k.astype(jnp.bfloat16))
+        for r in range(b):
+            lo, hi = int(offsets[r]), int(offsets[r] + lens[r])
+            pos = np.arange(lc)
+            out = (pos < lo) | (pos >= hi)
+            np.testing.assert_array_equal(
+                got[r][:, out].view(np.uint16), old[r][:, out].view(np.uint16))
+            inside = pos[~out]
+            np.testing.assert_array_equal(got[r][:, inside],
+                                          new_[r][:, inside - lo])
+
+    def test_prefill_has_no_cache_write_gather(self, tiny):
+        """The lowered batched prefill holds no gather in the
+        ``attn/cache_write`` scope (the check itself finds the old
+        formula's gather)."""
+        cfg, model, params = tiny
+        cache = model.init_cache(2, 32)
+        tokens = jnp.zeros((2, 8), jnp.int32)
+        lens = jnp.asarray([5, 8], jnp.int32)
+        offsets = jnp.asarray([0, 3], jnp.int32)
+        hlo = jax.jit(model.prefill).lower(
+            params, cache, tokens, lens, offsets).compile().as_text()
+        assert "attn/cache_write" in hlo
+        assert _cache_write_gathers(hlo) == []
+
+        def old(kc, k, offsets, lens):
+            with jax.named_scope("attn/cache_write"):
+                return _gather_write(kc, k, offsets, lens)
+
+        kc = jnp.zeros((2, 2, 32, 4), jnp.bfloat16)
+        k = jnp.ones((2, 2, 8, 4), jnp.float32)
+        ref = jax.jit(old).lower(kc, k, offsets, lens).compile().as_text()
+        assert _cache_write_gathers(ref)
 
 
 # -------------------------------------------------------------- admission --
