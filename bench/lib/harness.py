@@ -1,15 +1,20 @@
 """One cell of the benchmark: set-up, the measured window, the check.
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
-configuration in ``bench/configs/<config>.json``, its traffic in
-``bench/traffic/<traffic>.json`` and each metric's reader in
-``bench/metrics/<metric>.py``. Adding a configuration, a traffic mix or
-a metric adds files and entries; no file here changes.
+configuration in ``bench/configs/<config>.json``, the module that
+describes the configuration's model in ``bench/models/<model>.py`` (the
+configuration's ``"model"`` key; ``bench/lib/models.py``), its traffic
+in ``bench/traffic/<traffic>.json`` and each metric's reader in
+``bench/metrics/<metric>.py``. Adding a configuration, a model of
+another family, a traffic mix of either loop or a metric adds files and
+entries; no file here changes. A program family that the engine cannot
+serve, or a loop other than open and closed, would.
 
 The window drives ``ServeEngine.submit`` and ``step`` from one thread,
-as ``repro.launch.serve`` does, and submits each request at its
-scheduled arrival (an open loop). Every time is a host ``perf_counter``
-reading, relative to the window's start.
+as ``repro.launch.serve`` does. An open loop submits each request at its
+scheduled arrival; a closed loop keeps one request of each client in
+flight. Every time is a host ``perf_counter`` reading, relative to the
+window's opening.
 """
 from __future__ import annotations
 
@@ -30,7 +35,7 @@ from repro.data.pipeline import Request
 from repro.models.common import ArchConfig
 from repro.models.registry import get_model
 
-from . import reference, weights
+from . import models, reference, weights
 from .engine import BenchEngine, no_span, trace_span
 from .peaks import peaks_for
 from .traffic import Traffic
@@ -146,6 +151,9 @@ class Cell:
                                        .read_text())
         self.arch = dict(self.config["arch"])
         self.serve = dict(self.config["serve"])
+        self.model_file = models.of_config(self.root, self.config,
+                                           self.cell["config"])
+        self.module = models.load(self.model_file)
         self.span = trace_span if traced else no_span
         self.engine_cls = engine_cls
         devices = jax.devices()
@@ -167,11 +175,12 @@ class Cell:
 
     # ----------------------------------------------------------- set-up --
     def build(self, seed: int) -> None:
-        """Weights from ``seed``, the engine, and every program the
-        cell's traffic can reach."""
+        """Weights from ``seed``, the engine (every key of the
+        configuration's ``serve`` block goes to ``ServeConfig``), and
+        every program the cell's traffic can reach."""
         cfg = ArchConfig(**self.arch)
         self.model = get_model(cfg)
-        params = weights.program_params(self.arch, seed)
+        params = weights.program_params(self.module, self.arch, seed)
         want = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                            params)
@@ -183,19 +192,20 @@ class Cell:
         jax.block_until_ready(params)
         self.engine = self.engine_cls(
             self.model, params,
-            ServeConfig(max_batch=self.serve["max_batch"],
-                        max_seq=self.serve["max_seq"], eos_id=-1),
+            ServeConfig(eos_id=-1, **self.serve),
             span=self.span)
         self.warm(seed)
 
     def set_params(self, seed: int) -> None:
         """New weights from ``seed`` for the engine already built."""
-        self.engine.params = weights.program_params(self.arch, seed)
+        self.engine.params = weights.program_params(self.module, self.arch,
+                                                    seed)
         jax.block_until_ready(self.engine.params)
 
     def warm(self, seed: int) -> None:
         """Launch every (B, S) prefill bucket the traffic's clip can
-        reach once, at its largest batch, then every admission group size
+        reach once (with the engine's prompt chunks, where it splits
+        prompts), at its largest batch, then every admission group size
         at the smallest prompt bucket (the engine gathers and scatters
         cache rows per group size), each with one decode step."""
         eng = self.engine
@@ -209,7 +219,7 @@ class Cell:
         def b_bucket(n):
             return min(scfg.batch_policy.bucket("B", n), eng.n_slots)
 
-        s_pairs = tr.clip_buckets(s_bucket)
+        s_pairs = tr.clip_buckets(s_bucket, scfg.prefill_chunk)
         top_nb: Dict[int, int] = {}
         for nb in range(1, eng.n_slots + 1):
             top_nb[b_bucket(nb)] = nb
@@ -240,9 +250,19 @@ class Cell:
     def run(self, seed: int, seconds: float, *,
             traffic_spec: Optional[Dict] = None,
             trace_dir: Optional[str] = None) -> Dict:
-        """One measured window; returns the run record."""
+        """One measured window; returns the run record.
+
+        An open loop sends request ``i`` at its scheduled arrival. A
+        closed loop starts with every client sending, and a client sends
+        again ``think_s`` after the step that finished its last request;
+        a request's ``arrival`` is then the time it was sent. Its window
+        opens after ``lead_in_steps`` engine steps, and every time taken
+        before is moved to the window's clock (negative). Nothing is
+        sent once the window has closed; the engine then serves what was
+        sent until each request has its first token."""
         spec = traffic_spec or self.traffic_spec
         tr = Traffic(spec, seed, self.arch["vocab"])
+        closed_loop = tr.loop == "closed"
         eng = self.engine
         if eng.queue or any(s is not None for s in eng.slots):
             eng.run_until_done()    # an earlier window's requests
@@ -261,15 +281,24 @@ class Cell:
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        snap0 = {"buckets": {k: list(v) for k, v in mst.per_bucket.items()},
-                 "calls": mst.calls, "host_s": mst.host_seconds,
-                 "compiles": self.compiles.total}
-        win = self.span("bench.window")
-        win.__enter__()
+
+        def snap() -> Dict:
+            return {"buckets": {k: list(v) for k, v in
+                                mst.per_bucket.items()},
+                    "calls": mst.calls, "host_s": mst.host_seconds,
+                    "compiles": self.compiles.total}
+
+        lead_steps = tr.lead_in_steps if closed_loop else 0
         t0 = clock()
+        steps = 0
 
         def now() -> float:
             return clock() - t0
+
+        # closed loop: the client of each request in flight, and when
+        # each client sends next (None while its request is in flight)
+        client_of: Dict[int, int] = {}
+        ready = [0.0] * (tr.clients if closed_loop else 0)
 
         def new_request(i: int, at: float) -> Request:
             rec = ReqRec(rid=i, arrival=at, plen=tr.prompt_len(i),
@@ -278,8 +307,13 @@ class Cell:
             return Request(rid=i, tokens=tr.tokens(i),
                            max_new_tokens=rec.out_len - 1)
 
+        def finish(rid: int, t: float) -> None:
+            if rid in client_of:
+                ready[client_of.pop(rid)] = t + tr.think_s
+
         def observe(t: float) -> None:
-            """Admissions and token times after a step."""
+            """Admissions, token times and finished requests after a
+            step."""
             for s in eng.slots:
                 if s is not None and s.rid >= 0:
                     r = recs[s.rid]
@@ -292,45 +326,80 @@ class Cell:
                 if r.admit is None:
                     r.admit = t
                 r.tokens += [t] * (len(toks) - len(r.tokens))
+                finish(rid, t)
             done_seen[0] = len(items)
-            for rid in eng.failed:
+            for rid in list(eng.failed) + eng.rejected:
                 if rid in recs:
                     recs[rid].failed = True
+                    finish(rid, t)
 
+        def open_window() -> None:
+            """Move every time taken so far to the window's clock."""
+            nonlocal t0
+            shift = now()
+            t0 += shift
+            for r in recs.values():
+                r.arrival -= shift
+                r.submit = None if r.submit is None else r.submit - shift
+                r.admit = None if r.admit is None else r.admit - shift
+                r.tokens = [x - shift for x in r.tokens]
+            ready[:] = [None if at is None else at - shift for at in ready]
+            queue[:] = [(x - shift, n) for x, n in queue]
+
+        win = snap0 = snap1 = None
         closed_at = None
-        snap1 = None
+        lead_in_s = 0.0
         nxt = 0
         queue: List = []
         while True:
+            if snap0 is None and steps >= lead_steps:
+                lead_in_s = now()
+                open_window()
+                snap0 = snap()
+                # the profiler's span starts when it is made
+                win = self.span("bench.window")
+                win.__enter__()
             t = now()
             if closed_at is None:
-                # every arrival scheduled before the close is sent, late
-                # if a step ran past it
                 due = []
-                while tr.arrival(nxt) <= t and tr.arrival(nxt) < seconds:
-                    due.append(new_request(nxt, tr.arrival(nxt)))
-                    nxt += 1
+                if closed_loop:
+                    for c, at in enumerate(ready):
+                        if at is not None and at <= t and (
+                                snap0 is None or t < seconds):
+                            due.append(new_request(nxt, t))
+                            client_of[nxt] = c
+                            ready[c] = None
+                            nxt += 1
+                else:
+                    # every arrival scheduled before the close is sent,
+                    # late if a step ran past it
+                    while tr.arrival(nxt) <= t and tr.arrival(nxt) < seconds:
+                        due.append(new_request(nxt, tr.arrival(nxt)))
+                        nxt += 1
                 if due:
                     eng.submit(due)
                     ts = now()
                     for q in due:
                         recs[q.rid].submit = ts
-            if closed_at is None and t >= seconds:
+            if snap0 is not None and closed_at is None and t >= seconds:
                 closed_at = t
                 win.__exit__(None, None, None)
-                snap1 = {"buckets": {k: list(v) for k, v in
-                                     mst.per_bucket.items()},
-                         "calls": mst.calls, "host_s": mst.host_seconds,
-                         "compiles": self.compiles.total}
+                snap1 = snap()
             if eng.queue or any(s is not None for s in eng.slots):
                 eng.step()
+                steps += 1
                 observe(now())
                 queue.append((now(), len(eng.queue)))
             elif closed_at is not None:
                 break
             else:
+                if closed_loop:
+                    wake = min([at for at in ready if at is not None]
+                               + ([seconds] if snap0 is not None else []))
+                else:
+                    wake = min(tr.arrival(nxt), seconds)
                 with self.span("bench.idle"):
-                    time.sleep(max(0.0, min(tr.arrival(nxt), seconds) - now()))
+                    time.sleep(max(0.0, wake - now()))
             if closed_at is not None and all(r.tokens or r.failed
                                              for r in recs.values()):
                 break
@@ -351,6 +420,10 @@ class Cell:
 
         return {
             "workload": self.name,
+            "model": str(self.model_file),
+            "loop": tr.loop,
+            "lead_in_steps": lead_steps,
+            "lead_in_s": lead_in_s,
             "seed": int(seed),
             "seconds": float(seconds),
             "closed_at": closed_at,
@@ -413,7 +486,7 @@ class Cell:
         }
         if picked:
             gaps = reference.compare(
-                self.arch, seed,
+                self.module, self.arch, seed,
                 [(p["prompt"], p["plen"], np.asarray(p["served"]))
                  for p in picked], control=control)
             self.gaps = gaps

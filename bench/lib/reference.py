@@ -1,28 +1,22 @@
-"""Plain float32 reference of the served dense decoders, and its control.
+"""Plain float32 reference of a served model, and its control.
 
-Written from the architecture's equations in plain ``jax.numpy``,
+The model's own equations come from its module (``bench/lib/models.py``:
+``embed``, ``layer``, ``logits``), written in plain ``jax.numpy``,
 independent of the program: no kernels, no cache, no batching buckets.
 Every matrix product runs at ``Precision.HIGHEST`` (true float32 on a
 TPU). One layer is made and applied at a time, over blocks of rows, so
 the reference fits beside nothing else on one chip.
 
-Per layer: pre-norm (RMSNorm, eps 1e-6, with a scale; or LayerNorm,
-eps 1e-5, with a scale and a bias), causal self-attention with rotary
-positions (the two halves of each head rotated, base ``rope_theta``),
-grouped query heads (query head ``h`` reads key/value head
-``h // (n_heads / n_kv_heads)``), then the MLP: ``silu`` is gated,
-``silu(x W_gate) * (x W_in) W_out``; ``gelu`` is ``gelu(x W_in) W_out``
-with the tanh form of GELU. A final norm and the head (the embedding
-table's transpose where tied) give the logits.
-
 The control is the same computation with every matrix and the embedding
-rounded to float8 (e4m3, one scale per output channel): the precision
-below the bfloat16 the configurations serve in.
+that the model serves in bfloat16 rounded to float8 (e4m3, one scale per
+output channel, taken along the leaf's fan-in axis): the precision below
+the bfloat16 the configurations serve in. A leaf the program keeps in
+float32 (a router) stays float32.
 """
 from __future__ import annotations
 
 import functools
-import math
+from types import ModuleType
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -33,12 +27,7 @@ from . import weights as W
 
 __all__ = ["compare", "fake_fp8", "forward"]
 
-HIGHEST = jax.lax.Precision.HIGHEST
 _F8_MAX = 448.0   # largest finite float8_e4m3fn
-
-
-def _mm(x, w):
-    return jnp.matmul(x, w, precision=HIGHEST)
 
 
 def fake_fp8(w: jax.Array, axis: int) -> jax.Array:
@@ -50,92 +39,45 @@ def fake_fp8(w: jax.Array, axis: int) -> jax.Array:
     return (w / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _norm(a: Dict, p: Dict, x):
-    if a["norm"] == "layernorm":
-        mu = x.mean(-1, keepdims=True)
-        var = ((x - mu) ** 2).mean(-1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + 1e-5) * p["scale"] + p["bias"]
-    return x / jnp.sqrt((x * x).mean(-1, keepdims=True) + 1e-6) * p["scale"]
+def _q(control: bool):
+    """What a model module applies to a top-level table it reads."""
+    if control:
+        return fake_fp8
+    return lambda x, axis: x.astype(jnp.float32)
 
 
-def _rope(x, pos, theta: float):
-    """x (B, S, H, hd), pos (S,)."""
-    hd = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = pos.astype(jnp.float32)[:, None] * inv          # (S, hd/2)
-    c, s = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
+def _rounded(model: ModuleType, arch: Dict, layer: int) -> Tuple:
+    """``(path, fan_in)`` of the layer's leaves the control rounds."""
+    leaves = W.stack_leaves(model, arch, W.stack_of(model, arch, layer))
+    return tuple((lf.path, lf.fan_in) for lf in leaves
+                 if lf.kind == "matrix" and lf.dtype is None)
 
 
-def _gelu_tanh(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                     * (x + 0.044715 * x ** 3)))
-
-
-def _layer(a: Dict, w: Dict, x):
-    """One decoder layer over x (B, S, D), positions 0..S-1."""
-    b, s, _ = x.shape
-    h, hkv = a["n_heads"], a["n_kv_heads"]
-    hd = int(a.get("head_dim") or a["d_model"] // h)
-    pos = jnp.arange(s)
-    y = _norm(a, w["ln1"], x)
-    q = _rope(_mm(y, w["attn"]["wq"]).reshape(b, s, h, hd), pos,
-              a["rope_theta"])
-    k = _rope(_mm(y, w["attn"]["wk"]).reshape(b, s, hkv, hd), pos,
-              a["rope_theta"])
-    v = _mm(y, w["attn"]["wv"]).reshape(b, s, hkv, hd)
-    k = jnp.repeat(k, h // hkv, axis=2)
-    v = jnp.repeat(v, h // hkv, axis=2)
-    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST) / math.sqrt(hd)
-    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    sc = jnp.where(causal[None, None], sc, -jnp.inf)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, axis=-1), v,
-                   precision=HIGHEST).reshape(b, s, h * hd)
-    x = x + _mm(o, w["attn"]["wo"])
-    y = _norm(a, w["ln2"], x)
-    f = w["ffn"]
-    if a["act"] == "silu":
-        u = jax.nn.silu(_mm(y, f["w_gate"])) * _mm(y, f["w_in"])
-    else:
-        u = _gelu_tanh(_mm(y, f["w_in"]))
-    return x + _mm(u, f["w_out"])
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _apply_layer(arch_t: Tuple, control: bool, w: Dict, x):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _apply_layer(model: ModuleType, arch_t: Tuple, rounded: Tuple,
+                 control: bool, w: Dict, x):
     a = dict(arch_t)
+    axes = dict(rounded)
 
     def prep(path, leaf):
         leaf = leaf.astype(jnp.float32)
-        if control and leaf.ndim == 2:
-            return fake_fp8(leaf, axis=0)
+        key = tuple(k.key for k in path)
+        if control and key in axes:
+            return fake_fp8(leaf, axis=axes[key])
         return leaf
 
     w = jax.tree_util.tree_map_with_path(prep, w)
-    return _layer(a, w, x)
+    return model.layer(a, w, x)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _embed(arch_t: Tuple, control: bool, table, tokens):
-    rows = jnp.take(table, tokens, axis=0).astype(jnp.float32)
-    return fake_fp8(rows, axis=-1) if control else rows
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _embed(model: ModuleType, arch_t: Tuple, control: bool, top, tokens):
+    return model.embed(dict(arch_t), top, tokens, _q(control))
 
 
-def _head(a: Dict, top: Dict, control: bool, h):
-    ln = jax.tree.map(lambda t: t.astype(jnp.float32), top["ln_f"])
-    y = _norm(a, ln, h)
-    if a["tie_embeddings"]:
-        head = top["embed"].astype(jnp.float32).T
-    else:
-        head = top["head"].astype(jnp.float32)
-    if control:
-        head = fake_fp8(head, axis=0)
-    return _mm(y, head)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _gaps(arch_t: Tuple, chunk: int, top: Dict, h_ref, h_ctrl, tokens):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _gaps(model: ModuleType, arch_t: Tuple, chunk: int, top: Dict, h_ref,
+          h_ctrl, tokens):
     """Per position: the reference's best logit minus its logit of the
     served token, and (with ``h_ctrl``) minus its logit of the control's
     first choice; ``chunk`` positions at a time."""
@@ -143,12 +85,12 @@ def _gaps(arch_t: Tuple, chunk: int, top: Dict, h_ref, h_ctrl, tokens):
 
     def one(xs):
         hr, hc, tok = xs
-        ref = _head(a, top, False, hr)
+        ref = model.logits(a, top, hr, _q(False))
         best = ref.max(-1)
         served = best - jnp.take_along_axis(ref, tok[:, None], -1)[:, 0]
         if hc is None:
             return served, served
-        pick = _head(a, top, True, hc).argmax(-1)
+        pick = model.logits(a, top, hc, _q(True)).argmax(-1)
         return served, best - jnp.take_along_axis(ref, pick[:, None],
                                                   -1)[:, 0]
 
@@ -160,16 +102,10 @@ def _gaps(arch_t: Tuple, chunk: int, top: Dict, h_ref, h_ctrl, tokens):
     return served.reshape(-1), ctrl.reshape(-1)
 
 
-def _rows_per_block(a: Dict, s: int, budget: float = 1.5e9) -> int:
-    hd = int(a.get("head_dim") or a["d_model"] // a["n_heads"])
-    per_row = 4.0 * s * (2 * a["n_heads"] * s + 3 * a["d_ff"]
-                         + 4 * a["n_heads"] * hd)
-    return max(1, int(budget // per_row))
-
-
-def _hidden(arch: Dict, seed: int, seqs: Sequence[np.ndarray],
-            positions: Sequence[Sequence[int]], modes: Sequence[bool],
-            key, top: Dict, pad: int) -> Dict[bool, jax.Array]:
+def _hidden(model: ModuleType, arch: Dict, seed: int,
+            seqs: Sequence[np.ndarray], positions: Sequence[Sequence[int]],
+            modes: Sequence[bool], key, top: Dict,
+            pad: int) -> Dict[bool, jax.Array]:
     """Hidden states after the last layer at ``positions[i]`` of each
     sequence, concatenated over sequences and zero-padded to ``pad``
     rows, per mode (False: reference, True: control)."""
@@ -177,18 +113,20 @@ def _hidden(arch: Dict, seed: int, seqs: Sequence[np.ndarray],
     arch_t = W.frozen(a)
     n = len(seqs)
     s = -(-max(len(t) for t in seqs) // 512) * 512
-    rb = min(_rows_per_block(a, s), n)
+    rb = min(model.rows_per_block(a, s), n)
     nb = -(-n // rb)
     toks = np.zeros((nb * rb, s), np.int32)
     for i, t in enumerate(seqs):
         toks[i, :len(t)] = t
-    xs = {c: [_embed(arch_t, c, top["embed"],
+    xs = {c: [_embed(model, arch_t, c, top,
                      jnp.asarray(toks[j * rb:(j + 1) * rb]))
               for j in range(nb)] for c in modes}
     for layer in range(a["n_layers"]):
-        w = W.layer_weights(a, seed, layer, key)
+        w = W.layer_weights(model, a, seed, layer, key)
+        rounded = _rounded(model, a, layer)
         for c in modes:
-            xs[c] = [_apply_layer(arch_t, c, w, x) for x in xs[c]]
+            xs[c] = [_apply_layer(model, arch_t, rounded, c, w, x)
+                     for x in xs[c]]
         del w
     flat = np.concatenate([i * s + np.asarray(p, np.int64)
                            for i, p in enumerate(positions)])
@@ -202,8 +140,8 @@ def _take_rows(x, flat):
     return x.reshape(-1, x.shape[-1])[flat]
 
 
-def compare(arch: Dict, seed: int, seqs: Sequence[Tuple[np.ndarray, int,
-                                                        np.ndarray]],
+def compare(model: ModuleType, arch: Dict, seed: int,
+            seqs: Sequence[Tuple[np.ndarray, int, np.ndarray]],
             *, control: bool = False,
             chunk: int = 256) -> Dict[str, Optional[List[float]]]:
     """Gaps of served tokens against the reference.
@@ -223,13 +161,13 @@ def compare(arch: Dict, seed: int, seqs: Sequence[Tuple[np.ndarray, int,
         toks.append(served)
     modes = (False, True) if control else (False,)
     key = W.seed_key(seed)
-    top = W.top_weights(arch, seed, key)
+    top = W.top_weights(model, arch, seed, key)
     tok = np.concatenate(toks)
     t = len(tok)
     pad = -(-t // chunk) * chunk
     tok = np.pad(tok, (0, pad - t))
-    hid = _hidden(arch, seed, fulls, poss, modes, key, top, pad)
-    sg, cg = _gaps(W.frozen(arch), chunk, top, hid[False],
+    hid = _hidden(model, arch, seed, fulls, poss, modes, key, top, pad)
+    sg, cg = _gaps(model, W.frozen(arch), chunk, top, hid[False],
                    hid[True] if control else None, jnp.asarray(tok))
     bounds = np.cumsum([0] + [len(x) for x in toks])
 
@@ -241,16 +179,17 @@ def compare(arch: Dict, seed: int, seqs: Sequence[Tuple[np.ndarray, int,
             "control": per_request(cg) if control else None}
 
 
-def forward(arch: Dict, seed: int, tokens: np.ndarray, *,
+def forward(model: ModuleType, arch: Dict, seed: int, tokens: np.ndarray, *,
             control: bool = False) -> np.ndarray:
     """Logits at every position of ``tokens`` (B, S): the whole forward
     in one block, for small sizes."""
     a = dict(arch)
     arch_t = W.frozen(a)
     key = W.seed_key(seed)
-    top = W.top_weights(a, seed, key)
-    x = _embed(arch_t, control, top["embed"], jnp.asarray(tokens))
+    top = W.top_weights(model, a, seed, key)
+    x = _embed(model, arch_t, control, top, jnp.asarray(tokens))
     for layer in range(a["n_layers"]):
-        x = _apply_layer(arch_t, control, W.layer_weights(a, seed, layer,
-                                                          key), x)
-    return np.asarray(jax.jit(lambda t, h: _head(a, t, control, h))(top, x))
+        x = _apply_layer(model, arch_t, _rounded(model, a, layer), control,
+                         W.layer_weights(model, a, seed, layer, key), x)
+    return np.asarray(jax.jit(lambda t, h: model.logits(a, t, h,
+                                                        _q(control)))(top, x))

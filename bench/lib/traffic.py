@@ -4,6 +4,15 @@ A traffic file (``bench/traffic/<name>.json``) holds parameters only:
 
 * ``loop``: ``"open"``: requests arrive on a schedule, at ``rate_per_s``
   (:func:`window_rate` gives the rate at which a window holds one block);
+  or ``"closed"``: ``clients`` callers, each of which sends its next
+  request ``think_s`` seconds after its last one finished. The loop
+  runs ``lead_in_steps`` engine steps before the window opens (the
+  lead-in is neither timed nor counted in the set-up), so that the
+  window opens on a loaded engine, and at the same point of the step
+  sequence in every run: with no think time the sequence is the same
+  in every run, and a window that opened at a set time would meet it at
+  another phase each time. Request ``i`` is the ``i``-th sent, whichever
+  client sends it;
 * ``prompt_len`` and ``output_len``: lognormal distributions, each given
   by ``median``, ``sigma`` and the clip ``[min, max]``;
 * ``block``: how many consecutive requests form one stratified block.
@@ -16,7 +25,9 @@ over ``[2, vocab)`` (ids 0 and 1 are the pad and end-of-sequence ids of
 the served models). A window holds a few dozen requests, and there the
 order of the gaps decides the queueing: with the order drawn from the
 seed, the p90 of the time to first token would differ between seeds by
-far more than between two runs of one seed.
+far more than between two runs of one seed. A closed loop has no
+schedule: with no think time its requests are sent as steps finish, in
+an order that the lengths alone decide.
 """
 from __future__ import annotations
 
@@ -25,7 +36,7 @@ import math
 import pathlib
 from dataclasses import dataclass
 from statistics import NormalDist
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -57,8 +68,6 @@ class LengthDist:
         return np.clip(np.rint(q), self.lo, self.hi).astype(np.int64)
 
 
-
-
 def _unit_gaps(block: int) -> List[float]:
     """A block's stratified arrival gaps at one request a second: the
     exponential distribution's quantiles at ``(k + 0.5) / block``."""
@@ -83,15 +92,25 @@ class Traffic:
     """
 
     def __init__(self, spec: Dict, seed: int, vocab: int):
-        if spec["loop"] != "open":
-            raise ValueError(f"unknown loop {spec['loop']!r}")
+        self.loop = spec["loop"]
+        if self.loop not in ("open", "closed"):
+            raise ValueError(f"unknown loop {self.loop!r}")
         self.spec = spec
         self.seed = int(seed)
         self.vocab = int(vocab)
         self.block = int(spec.get("block", 64))
         self.prompt = LengthDist.from_spec(spec["prompt_len"])
         self.output = LengthDist.from_spec(spec["output_len"])
-        self.rate = float(spec["rate_per_s"])
+        if self.loop == "open":
+            self.rate = float(spec["rate_per_s"])
+        else:
+            self.clients = int(spec["clients"])
+            self.think_s = float(spec["think_s"])
+            self.lead_in_steps = int(spec["lead_in_steps"])
+            if self.clients < 1 or self.think_s < 0 or \
+                    self.lead_in_steps < 0:
+                raise ValueError(f"a closed loop needs clients >= 1 and "
+                                 f"think_s, lead_in_steps >= 0: {spec}")
         self._blocks: Dict[tuple, np.ndarray] = {}
         self._arrivals: List[float] = []
 
@@ -117,7 +136,7 @@ class Traffic:
                             dtype=np.int32)
 
     def arrival(self, i: int) -> float:
-        """Scheduled arrival of request ``i`` (seconds)."""
+        """Scheduled arrival of request ``i`` (seconds); open loop only."""
         gaps = [g / self.rate for g in _unit_gaps(self.block)]
         while len(self._arrivals) <= i:
             k = len(self._arrivals)
@@ -126,12 +145,20 @@ class Traffic:
                 self._strat(_STREAM_GAP, np.asarray(gaps), k)))
         return self._arrivals[i]
 
-    def clip_buckets(self, bucket) -> List[int]:
-        """The distinct values of ``bucket(n)`` over every prompt length
-        the clip allows, each with the largest such length:
-        ``[(bucket, length), ...]`` in increasing order."""
-        out: Dict[int, int] = {}
+    def clip_buckets(self, bucket, chunk: Optional[int] = None) -> List:
+        """The distinct values of ``bucket(n)`` over every length that a
+        prefill launch can take: every prompt length the clip allows or,
+        where the engine splits prompts into chunks of at most ``chunk``
+        tokens, every chunk of such a prompt; each with the largest such
+        length: ``[(bucket, length), ...]`` in increasing order."""
+        lens = set()
         for n in range(self.prompt.lo, self.prompt.hi + 1):
+            while chunk is not None and n > chunk:
+                lens.add(chunk)
+                n -= chunk
+            lens.add(n)
+        out: Dict[int, int] = {}
+        for n in sorted(lens):
             out[bucket(n)] = n
         return sorted(out.items())
 

@@ -4,4 +4,5 @@ seconds (host clock)."""
 
 def read(run):
     s = run["seconds"]
-    return sum(1 for r in run["requests"] for t in r["tokens"] if t <= s) / s
+    return sum(1 for r in run["requests"] for t in r["tokens"]
+               if 0 <= t <= s) / s

@@ -1,7 +1,7 @@
 """Model step: model FLOPs of the window's true prompt tokens over the
 host wall of their prefill launches (from the gather of rows to the
 first tokens back on the host) times peak bf16 FLOP/s."""
-from bench.lib import flops
+from bench.lib import flops, models
 from bench.lib.readings import peak_flops, prefills
 
 
@@ -10,5 +10,6 @@ def read(run):
     wall = sum(p[1] - p[0] for p in ps)
     if not wall or not run.get("peaks"):
         return None
-    work = sum(flops.prefill_flops(run["arch"], p[2]) for p in ps)
+    m, a = models.of_run(run), run["arch"]
+    work = sum(flops.prefill_flops(m, a, p[2]) for p in ps)
     return 100.0 * work / (wall * peak_flops(run))

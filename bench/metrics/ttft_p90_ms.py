@@ -1,6 +1,7 @@
 """90th percentile, over every request scheduled to arrive inside the
-window, of the time from its scheduled arrival to the host time its
-first token came back. Requests still waiting at the window's close are
+window (in a closed loop: sent inside it), of the time from its
+scheduled arrival (the time it was sent) to the host time its first
+token came back. Requests still waiting at the window's close are
 served, with no new arrivals, until each has its first token; one that
 failed counts the whole run."""
 from bench.lib.readings import percentile, window_requests

@@ -1,6 +1,6 @@
 """Tiny cells for the benchmark's CPU tests: a root that holds a
 ``BENCHMARK.json``, configuration and traffic files of CPU size, and the
-real metric readers."""
+real model modules and metric readers."""
 from __future__ import annotations
 
 import json
@@ -12,6 +12,10 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
+
+from bench.lib import models  # noqa: E402
+
+DENSE = models.load(ROOT / "bench" / "models" / "dense_decoder.py")
 
 TINY_ARCH = {
     "dense_gqa": {"name": "tiny-gqa", "family": "dense", "n_layers": 2,
@@ -31,6 +35,13 @@ TINY_TRAFFIC = {
              "prompt_len": {"median": 40, "sigma": 0.5, "min": 8, "max": 100},
              "output_len": {"median": 6, "sigma": 0.5, "min": 1, "max": 16},
              "block": 16},
+    "closed": {"loop": "closed", "clients": 6, "think_s": 0.0,
+               "lead_in_steps": 12,
+               "prompt_len": {"median": 40, "sigma": 0.5, "min": 8,
+                              "max": 100},
+               "output_len": {"median": 6, "sigma": 0.5, "min": 2,
+                              "max": 16},
+               "block": 16},
 }
 
 # limits on the widest logit gap at these sizes, set from six seeds on
@@ -42,19 +53,23 @@ TINY_GAP_LIMIT = {"dense_gqa": 0.06, "dense_mqa": 0.007}
 
 
 def make_root(tmp: pathlib.Path) -> pathlib.Path:
-    """A benchmark root with one tiny cell per tiny architecture (the GQA
-    one named as the cell on the chip, so that it reports that cell's
-    metrics) and the repository's metric readers. Every finished request
-    is compared, so that a fault which alters some rows of a launch
-    cannot slip past the sample."""
+    """A benchmark root with tiny cells (the open-loop GQA one and the
+    closed-loop MQA one named as the cells on the chip, so that they
+    report those cells' metrics), the repository's model modules and its
+    metric readers. Every finished request is compared, so that a fault
+    which alters some rows of a launch cannot slip past the sample."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir(parents=True)
-    shutil.copytree(ROOT / "bench" / "metrics", tmp / "bench" / "metrics")
+    for d in ("metrics", "models"):
+        shutil.copytree(ROOT / "bench" / d, tmp / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     cells = {"minitron_4b.code_completion": ("dense_gqa", "open"),
-             "tiny_mqa.code_completion": ("dense_mqa", "open")}
+             "tiny_mqa.code_completion": ("dense_mqa", "open"),
+             "granite_20b.chat_backlog": ("dense_mqa", "closed")}
     for cfg, arch in TINY_ARCH.items():
         (tmp / "bench" / "configs" / f"{cfg}.json").write_text(json.dumps({
+            "model": "dense_decoder",
             "arch": arch, "serve": {"max_batch": 4, "max_seq": 128},
             "check": {"requests": 1000, "min_tokens": 8,
                       "logit_gap": TINY_GAP_LIMIT[cfg]}}))

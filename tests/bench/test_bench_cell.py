@@ -1,6 +1,7 @@
-"""Whole runs of the harness on the CPU at tiny sizes: the entry refuses
-to run without a chip; past that check, a sound run is correct, and each
-fault of the timed path and the float8 control make it incorrect."""
+"""Whole runs of the harness on the CPU at tiny sizes, open loop and
+closed: the entry refuses to run without a chip; past that check, a
+sound run is correct, and each fault of the timed path and the float8
+control make it incorrect."""
 import json
 import os
 import shutil
@@ -12,7 +13,8 @@ import pytest
 from bench_tiny import ROOT, fault_engine_cls, make_root
 from bench.lib import harness
 
-CELLS = ["minitron_4b.code_completion", "tiny_mqa.code_completion"]
+CELLS = ["minitron_4b.code_completion", "tiny_mqa.code_completion",
+         "granite_20b.chat_backlog"]
 FAULTS = ["stale_state", "half_batch", "altered_token"]
 SEED = 2**31 + 4321
 

@@ -19,7 +19,8 @@ def test_new_files_are_picked_up(tmp_path):
     before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
     arch = dict(TINY_ARCH["dense_gqa"], name="tiny-new", n_layers=1)
     (root / "bench" / "configs" / "tiny_new.json").write_text(json.dumps({
-        "arch": arch, "serve": {"max_batch": 2, "max_seq": 64},
+        "model": "dense_decoder", "arch": arch,
+        "serve": {"max_batch": 2, "max_seq": 64},
         "check": {"requests": 2, "min_tokens": 2, "logit_gap": 0.04}}))
     (root / "bench" / "traffic" / "short_burst.json").write_text(json.dumps({
         "loop": "open", "rate_per_s": 50, "block": 8,

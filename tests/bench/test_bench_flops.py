@@ -5,23 +5,11 @@ import jax
 import numpy as np
 import pytest
 
-from bench_tiny import ROOT
+from bench_tiny import DENSE, ROOT
 from bench.lib import flops
 
 
-# Granite-20B-Code (arXiv:2405.04324) as one 13-layer stage of a 52-layer
-# pipeline with a tied head: MQA, a non-gated GELU MLP, LayerNorm
-GRANITE_STAGE = {"name": "granite-20b", "family": "dense", "n_layers": 13,
-                 "d_model": 6144, "n_heads": 48, "n_kv_heads": 1,
-                 "head_dim": 128, "d_ff": 24576, "vocab": 49152,
-                 "dtype": "bf16", "act": "gelu", "norm": "layernorm",
-                 "rope_theta": 10000.0, "tie_embeddings": True,
-                 "remat": "full", "max_seq": 32768}
-
-
 def arch(name):
-    if name == "granite_20b":
-        return GRANITE_STAGE
     return json.loads((ROOT / "bench" / "configs" / f"{name}.json")
                       .read_text())["arch"]
 
@@ -39,8 +27,8 @@ HAND = {
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_layer_params_and_kv_bytes(name):
     a = arch(name)
-    assert flops.layer_matmul_params(a) == HAND[name]["layer"]
-    assert flops.kv_bytes_per_token(a) == HAND[name]["kv"]
+    assert DENSE.layer_matmul_params(a) == HAND[name]["layer"]
+    assert DENSE.kv_bytes_per_token(a) == HAND[name]["kv"]
 
 
 def test_hand_counts_at_published_widths():
@@ -63,7 +51,7 @@ def test_weight_bytes_match_the_model(name):
     total = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                 for x in jax.tree.leaves(shapes))
     table = 0 if a["tie_embeddings"] else a["vocab"] * a["d_model"] * 2
-    assert flops.weight_bytes(a) == total - table
+    assert DENSE.weight_bytes(a) == total - table
 
 
 def test_minitron_counts():
@@ -75,26 +63,29 @@ def test_minitron_counts():
     per_tok = 2 * 32 * 81_788_928
     head = 2 * 3072 * 256_000
     att = 4 * 32 * 24 * 128
-    assert flops.decode_flops(a, [0]) == per_tok + head + att
-    assert flops.decode_flops(a, [9, 99]) == \
+    assert flops.decode_flops(DENSE, a, [0]) == per_tok + head + att
+    assert flops.decode_flops(DENSE, a, [9, 99]) == \
         2 * (per_tok + head) + att * (10 + 100)
-    assert flops.prefill_flops(a, [1]) == flops.decode_flops(a, [0])
-    assert flops.prefill_flops(a, [1500]) == \
+    assert flops.prefill_flops(DENSE, a, [1]) == \
+        flops.decode_flops(DENSE, a, [0])
+    assert flops.prefill_flops(DENSE, a, [1500]) == \
         per_tok * 1500 + att * 1500 * 1501 / 2 + head
-    assert flops.decode_bytes(a, [9]) == (flops.weight_bytes(a)
+    assert flops.decode_bytes(DENSE, a, [9]) == (DENSE.weight_bytes(a)
                                           + 131_072 * 10 + 3072 * 2)
-    assert flops.prefill_bytes(a, [100, 50]) == (
-        flops.weight_bytes(a) + 150 * 3072 * 2 + 150 * 131_072)
+    assert flops.prefill_bytes(DENSE, a, [100, 50]) == (
+        DENSE.weight_bytes(a) + 150 * 3072 * 2 + 150 * 131_072)
 
 
 def test_granite_weight_bytes_and_least_time():
     a = arch("granite_20b")
-    assert flops.weight_bytes(a) == 13 * (379_060_224 * 2 + 2 * 2 * 6144 * 4) \
-        + 2 * 6144 * 4 + 6144 * 49152 * 2 == 10_460_872_704
+    assert DENSE.weight_bytes(a) == \
+        13 * (379_060_224 * 2 + 2 * 2 * 6144 * 4) + 2 * 6144 * 4 \
+        + 6144 * 49152 * 2 == 10_460_872_704
     # one decode row is bound by bytes, a long prefill by operations
-    t = flops.least_time(flops.decode_flops(a, [255]),
-                         flops.decode_bytes(a, [255]), 197e12, 819e9)
-    assert t == pytest.approx(flops.decode_bytes(a, [255]) / 819e9)
-    t = flops.least_time(flops.prefill_flops(a, [1024] * 8),
-                         flops.prefill_bytes(a, [1024] * 8), 197e12, 819e9)
-    assert t == pytest.approx(flops.prefill_flops(a, [1024] * 8) / 197e12)
+    t = flops.least_time(flops.decode_flops(DENSE, a, [255]),
+                         flops.decode_bytes(DENSE, a, [255]), 197e12, 819e9)
+    assert t == pytest.approx(flops.decode_bytes(DENSE, a, [255]) / 819e9)
+    lens = [1024] * 8
+    t = flops.least_time(flops.prefill_flops(DENSE, a, lens),
+                         flops.prefill_bytes(DENSE, a, lens), 197e12, 819e9)
+    assert t == pytest.approx(flops.prefill_flops(DENSE, a, lens) / 197e12)

@@ -4,7 +4,7 @@ import json
 
 import pytest
 
-from bench_tiny import ROOT, TINY_ARCH
+from bench_tiny import DENSE, ROOT, TINY_ARCH
 from bench.lib import flops
 
 A = TINY_ARCH["dense_gqa"]
@@ -15,6 +15,7 @@ def canned(**kw):
     run = {
         "seconds": 10.0, "closed_at": 10.2, "end": 11.0, "setup_s": 42.0,
         "arch": A, "peaks": PEAKS,
+        "model": str(DENSE.__file__),
         "requests": [
             {"rid": 0, "arrival": 1.0, "admit": 1.5, "tokens": [2.0, 3.0, 4.0]},
             {"rid": 1, "arrival": 5.0, "admit": 5.1, "tokens": [5.5, 10.5]},
@@ -33,7 +34,8 @@ def canned(**kw):
 
 def read(name, run):
     path = ROOT / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"m_{name}", path)
+    spec = importlib.util.spec_from_file_location(
+        f"m_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read(run)
@@ -43,8 +45,9 @@ def least(f, b):
     return flops.least_time(f, b, PEAKS["flops_bf16"], PEAKS["hbm_bw"])
 
 
-PF = flops.prefill_flops(A, [100, 50])
-DF = flops.decode_flops(A, [101, 51]) + flops.decode_flops(A, [102])
+PF = flops.prefill_flops(DENSE, A, [100, 50])
+DF = (flops.decode_flops(DENSE, A, [101, 51])
+      + flops.decode_flops(DENSE, A, [102]))
 EXPECTED = {
     # tokens back by 10 s: 3 of r0, 1 of r1
     "output_tok_s": 0.4,
@@ -57,18 +60,25 @@ EXPECTED = {
     "queue_wait_p90_ms": 980.0,
     "prefill_pad_share": 100 * (1 - 150 / 256),
     "prefill_dispatch_host_us": 200.0,
-    "prefill_roofline": 100 * least(PF, flops.prefill_bytes(A, [100, 50]))
-    / 0.5,
+    "prefill_roofline":
+    100 * least(PF, flops.prefill_bytes(DENSE, A, [100, 50])) / 0.5,
     "prefill_mfu": 100 * PF / (0.6 * 1e12),
     "decode_rows_per_step": 1.5,
     "decode_roofline": 100 * (
-        least(flops.decode_flops(A, [101, 51]),
-              flops.decode_bytes(A, [101, 51]))
-        + least(flops.decode_flops(A, [102]), flops.decode_bytes(A, [102])))
+        least(flops.decode_flops(DENSE, A, [101, 51]),
+              flops.decode_bytes(DENSE, A, [101, 51]))
+        + least(flops.decode_flops(DENSE, A, [102]),
+                flops.decode_bytes(DENSE, A, [102])))
     / 0.8,
     "step_mfu": 100 * (PF + DF) / (10 * 1e12),
     "device_idle_share": 30.0,
 }
+# the closed-loop cell's split of the same readings (its end-to-end
+# one carries a bound of its own), and the open-loop cell's per-layer
+# token gap
+EXPECTED.update({f"{n}.backlog": EXPECTED[n] for n in (
+    "output_tok_s", "step_mfu", "decode_roofline", "device_idle_share")})
+EXPECTED["itl_p99_ms.open"] = EXPECTED["itl_p99_ms"]
 
 
 def test_every_metric_has_a_reader_and_a_case():
@@ -81,6 +91,37 @@ def test_every_metric_has_a_reader_and_a_case():
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_reader_on_canned_run(name):
     assert read(name, canned()) == pytest.approx(EXPECTED[name])
+
+
+def with_lead_in():
+    """The canned run after a closed loop's lead-in: a request sent, a
+    token returned, a prefill and a decode step, all before the window
+    opened."""
+    run = canned()
+    run["requests"].append({"rid": 9, "arrival": -2.0, "admit": -1.5,
+                            "tokens": [-1.0, -0.5, 0.5, 11.0]})
+    run["prefills"].insert(0, (-1.8, -1.0, [64]))
+    run["decodes"].insert(0, (-0.9, -0.5, [64, 12]))
+    return run
+
+
+@pytest.mark.parametrize("name", sorted(set(EXPECTED) - {
+    "output_tok_s", "output_tok_s.backlog", "itl_p99_ms",
+    "itl_p99_ms.open"}))
+def test_reader_ignores_the_lead_in(name):
+    assert read(name, with_lead_in()) == pytest.approx(EXPECTED[name])
+
+
+@pytest.mark.parametrize("name", ["output_tok_s", "output_tok_s.backlog"])
+def test_tokens_of_the_lead_in_are_not_counted(name):
+    # the lead-in request's one token inside the window counts
+    assert read(name, with_lead_in()) == pytest.approx(0.5)
+
+
+@pytest.mark.parametrize("name", ["itl_p99_ms", "itl_p99_ms.open"])
+def test_gaps_of_the_lead_in_are_not_counted(name):
+    # its gaps that closed in the window: 0.5 - (-0.5) = 1.0 s, as r0's
+    assert read(name, with_lead_in()) == pytest.approx(1000.0)
 
 
 @pytest.mark.parametrize("name", ["prefill_roofline", "decode_roofline",

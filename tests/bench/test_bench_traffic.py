@@ -10,11 +10,12 @@ from bench.lib.traffic import LengthDist, Traffic, window_rate
 
 SEED = 2**31 + 12345   # larger than 32 signed bits hold
 NAMES = ["code_completion", "tiny_open"]
+CLOSED = ["chat_backlog", "tiny_closed"]
 
 
 def spec(name):
-    if name == "tiny_open":
-        return TINY_TRAFFIC["open"]
+    if name.startswith("tiny_"):
+        return TINY_TRAFFIC[name[len("tiny_"):]]
     return json.loads((ROOT / "bench" / "traffic" / f"{name}.json")
                       .read_text())
 
@@ -45,7 +46,7 @@ def test_other_seed_same_schedule_other_tokens(name):
     assert pa[:n // 2] != pa[n // 2:]
 
 
-@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("name", NAMES + CLOSED)
 @pytest.mark.parametrize("which", ["prompt_len", "output_len"])
 def test_lengths_match_median_and_clip(name, which):
     s = spec(name)
@@ -74,11 +75,66 @@ def test_open_loop_rate_and_token_ids():
     assert ids.min() >= 2 and ids.max() < 1000
 
 
+def pow2(n):
+    return max(16, 1 << (n - 1).bit_length())
+
+
 def test_clip_buckets():
     tr = Traffic(spec("code_completion"), SEED, 1000)
-    pairs = tr.clip_buckets(lambda n: max(16, 1 << (n - 1).bit_length()))
+    pairs = tr.clip_buckets(pow2)
     assert pairs == [(64, 64), (128, 128), (256, 256), (512, 512),
                      (1024, 1024), (2048, 1920)]
+    # chunks no shorter than the longest prompt change nothing
+    assert tr.clip_buckets(pow2, 1920) == pairs
+
+
+def test_clip_buckets_of_chunked_prompts():
+    """Prompts of 4-1024 tokens in chunks of 256: every chunk is at most
+    256 long, and the last chunk of a prompt can be any length."""
+    tr = Traffic(spec("chat_backlog"), SEED, 1000)
+    assert tr.clip_buckets(pow2, 256) == [(16, 16), (32, 32), (64, 64),
+                                          (128, 128), (256, 256)]
+    assert tr.clip_buckets(pow2) == [(16, 16), (32, 32), (64, 64),
+                                     (128, 128), (256, 256), (512, 512),
+                                     (1024, 1024)]
+
+
+@pytest.mark.parametrize("which,mean", [("prompt_len", 161.31),
+                                        ("output_len", 337.99)])
+def test_chat_backlog_has_the_sources_means(which, mean):
+    """The ShareGPT means that the vLLM paper gives (Sec. 6.1, Fig. 11):
+    every block of stratified lengths has them, within 0.1%."""
+    s = spec("chat_backlog")
+    tr = Traffic(s, SEED, 50000)
+    n = s["block"]
+    for b in range(3):
+        xs = [getattr(tr, which)(i) for i in range(b * n, (b + 1) * n)]
+        assert np.mean(xs) == pytest.approx(mean, rel=1e-3)
+
+
+@pytest.mark.parametrize("name", CLOSED)
+def test_closed_loop_lengths_depend_on_the_index_alone(name):
+    """Request i has the same lengths under every seed (and in an open
+    loop of the same distributions), whichever client sends it; the seed
+    draws its token ids."""
+    s = spec(name)
+    a, b = Traffic(s, SEED, 50000), Traffic(s, SEED + 1, 50000)
+    opened = Traffic(dict(s, loop="open", rate_per_s=1.0), SEED, 50000)
+    n = 2 * s["block"]
+    for f in ("prompt_len", "output_len"):
+        assert [getattr(a, f)(i) for i in range(n)] == \
+            [getattr(b, f)(i) for i in range(n)] == \
+            [getattr(opened, f)(i) for i in range(n)]
+    assert not np.array_equal(a.tokens(0), b.tokens(0))
+    assert (a.clients, a.think_s, a.lead_in_steps) == \
+        (s["clients"], s["think_s"], s["lead_in_steps"])
+
+
+@pytest.mark.parametrize("bad", [{"loop": "poisson"}, {"clients": 0},
+                                 {"lead_in_steps": -1}])
+def test_a_malformed_loop_is_refused(bad):
+    with pytest.raises(ValueError):
+        Traffic(dict(spec("tiny_closed"), **bad), SEED, 1000)
 
 
 @pytest.mark.parametrize("seed", [SEED, 7, 2**33 + 5])
